@@ -23,6 +23,9 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     quick = args.quick or args.smoke
 
+    from repro import api
+    api.enable_compile_cache()
+
     from benchmarks import (bench_ablation, bench_cache, bench_ckpt_store,
                             bench_dcr, bench_dims, bench_gc, bench_ingest,
                             bench_kernels, bench_objstore, bench_restore,

@@ -44,6 +44,7 @@ def main():
     ap.add_argument("--range-reads", type=int, default=64,
                     help="random 64 KiB partial reads in the serving phase")
     args = ap.parse_args()
+    api.enable_compile_cache()
 
     for wl in ("sql_dump", "vmdk", "kernel"):
         versions = make_workload(wl, WorkloadConfig(

@@ -12,6 +12,7 @@ from repro.data import make_workload, WorkloadConfig
 
 
 def main():
+    api.enable_compile_cache()
     versions = make_workload("sql_dump", WorkloadConfig(base_size=2 << 20, versions=3))
     print(f"workload: {len(versions)} versions x {len(versions[0]) >> 20} MiB")
     print(f"registered detectors: {api.available_detectors()}")

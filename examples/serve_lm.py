@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api import enable_compile_cache
 from repro.configs import get_config
 from repro.models import make_model
 
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--gen", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = make_model(cfg)

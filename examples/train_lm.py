@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro import optim
+from repro.api import enable_compile_cache
 from repro.checkpoint import DedupCheckpointStore
 from repro.configs import get_config
 from repro.data import TokenPipeline, TokenPipelineConfig
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--params", choices=["tiny", "100m"], default="tiny")
     ap.add_argument("--checkpoint-every", type=int, default=20)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("granite-8b").reduced()
     if args.params == "100m":

@@ -130,6 +130,7 @@ from repro.api.config import (  # noqa: F401
     build_policy,
     build_server,
     build_store,
+    enable_compile_cache,
 )
 
 _OBJECTSTORE_EXPORTS = frozenset({

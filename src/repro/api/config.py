@@ -27,6 +27,8 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import os
+import pathlib
 from typing import Any
 
 from repro.api import registry
@@ -41,6 +43,32 @@ _KNOWN_KEYS = {"detector", "detector_args", "chunker", "chunker_args",
                "verify_reads", "retry_deadline",
                "trace_path", "trace_ring_events",
                "server_workers", "server_args", "tenant_args"}
+
+# the checkout root (src/repro/api/config.py -> three levels up from src)
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Call before the first compile, never at import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no other path is set here. Otherwise the cache lives at a fixed path
+    inside the checkout (``.jax_cache``, git-ignored): the path is part
+    of the cache key, so a temporary or per-run name would never hit.
+    Either way every program is cached, not only those that took over a
+    second to compile (JAX's default): the store's path is many small
+    programs, each shape bucket its own.
+    """
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 # serving/integrity knobs (DESIGN.md §10, §11.3, §13) -> backend factory
 # kwargs; each is forwarded only when set and only to factories that

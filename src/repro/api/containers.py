@@ -1323,6 +1323,11 @@ class FileBackend(PlannedChainReader):
                     good_end = f.tell()
             if good_end < size:   # drop the torn bytes so later appends
                 os.truncate(self._log_path, good_end)   # start on a boundary
+            if good_end == 0:
+                # nothing survived (e.g. a tear inside the 12-byte log
+                # header): the log is empty and gets a fresh RCL2 header
+                # below, so its records must be v2 as well
+                self._log_v2 = True
         if self._recipes_path.exists():
             good_end = 0
             torn = False

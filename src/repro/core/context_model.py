@@ -99,6 +99,13 @@ def _train_step(params, opt_state, ctx, tgt, cfg, tx):
     return params, opt_state, loss
 
 
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _project(f: jax.Array, u_pinv: jax.Array, scale: int) -> jax.Array:
+    """Formula 3 as one device program: [*, M] -> L2-normalized [*, D]."""
+    v = scale * (f @ u_pinv)
+    return v / (jnp.linalg.norm(v, axis=-1, keepdims=True) + 1e-12)
+
+
 class ContextModel:
     """Train-then-predict wrapper used by the dedup pipeline."""
 
@@ -138,6 +145,4 @@ class ContextModel:
         """
         assert self.params is not None, "fit() first"
         f = jnp.asarray(features, jnp.float32)
-        v = (2 * self.cfg.k) * (f @ self._u_pinv)
-        v = v / (jnp.linalg.norm(v, axis=-1, keepdims=True) + 1e-12)
-        return np.asarray(v)
+        return np.asarray(_project(f, self._u_pinv, 2 * self.cfg.k))

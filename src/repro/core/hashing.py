@@ -163,9 +163,6 @@ def segment_poly_hashes_np(data: np.ndarray, bounds: np.ndarray) -> np.ndarray:
 # jnp implementations (oracles for the Pallas kernels; also usable directly)
 # ----------------------------------------------------------------------------
 
-GEAR_TABLE_J = jnp.asarray(GEAR_TABLE)
-
-
 def windowed_weighted_sum_j(g: jax.Array, weights: np.ndarray) -> jax.Array:
     """h_i = sum_k weights[k] * g_{i-k} (uint32 wraparound), pure jnp.
 
@@ -186,7 +183,7 @@ def windowed_weighted_sum_j(g: jax.Array, weights: np.ndarray) -> jax.Array:
 
 
 def gear_hashes_j(data: jax.Array) -> jax.Array:
-    g = GEAR_TABLE_J[data.astype(jnp.int32)]
+    g = jnp.asarray(GEAR_TABLE)[data.astype(jnp.int32)]
     return windowed_weighted_sum_j(g, GEAR_WEIGHTS)
 
 

@@ -4,6 +4,5 @@ from repro.distributed.sharding import (  # noqa: F401
     constrain,
     default_rules,
     param_pspecs,
-    shard_map,
     use_rules,
 )

@@ -68,8 +68,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
         ys = emits[s - 1:]
         return ys
 
-    from repro.distributed.sharding import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),        # params staged; microbatches replicated
         out_specs=P(axis),              # [S, M, mb, ...]; only last stage valid

@@ -107,24 +107,6 @@ def use_rules(rules: Optional[ShardingRules], mesh: Optional[Mesh] = None):
         _ACTIVE_MESH.reset(tok_m)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.5 hoisted shard_map to ``jax.shard_map`` and renamed the
-    replication-check kwarg to ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(check_rep=...)``. Same
-    semantics either way, so everything in the repo routes through here
-    (the same compat seam as ``repro.launch.mesh.make_mesh``).
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check_vma)
-
-
 def current_rules() -> Optional[ShardingRules]:
     return _ACTIVE.get()
 

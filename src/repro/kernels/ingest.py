@@ -70,27 +70,27 @@ _FLOOR_STREAM = 1 << 16
 # FeatureExtractor route oversized streams to the per-chunk host path.
 FUSED_STREAM_LIMIT = 2**31 - 2**20
 
-# Reusable pinned host staging buffers, one per stream bucket. Safe to
-# overwrite between scans: the scan program has fully executed (its
-# candidate bitmaps are materialized to host) before scan_stream returns.
-# Buckets past the cap are allocated transiently so one huge stream does
-# not pin its buffer for process lifetime.
+# Reusable host staging buffers, one per stream bucket. Safe to overwrite
+# between scans: the scan program has fully executed (its candidate
+# bitmaps are materialized to host) before scan_stream returns, so the
+# host-to-device copy of the previous stream is complete. Buckets past
+# the cap are allocated transiently so one huge stream does not pin its
+# buffer for process lifetime.
 _SCAN_BUFS: dict[int, np.ndarray] = {}
 _SCAN_BUF_CACHE_CAP = 64 << 20
 
 
 def _stage(data: np.ndarray, spad: int) -> jax.Array:
-    """Zero-copy (dlpack) handoff of a bucket-padded host buffer."""
+    """Copy a bucket-padded host buffer to the default device. The result
+    is uncommitted, so the scan runs wherever ``jax.default_device`` (or,
+    unset, the default backend) points: the chip on a TPU host."""
     buf = _SCAN_BUFS.get(spad)
     if buf is None:
         buf = np.zeros(spad, np.uint8)
         if spad <= _SCAN_BUF_CACHE_CAP:
             _SCAN_BUFS[spad] = buf
     buf[:len(data)] = data
-    try:
-        return jnp.from_dlpack(buf)
-    except Exception:        # older jax / exotic layouts: plain copy
-        return jnp.asarray(buf)
+    return jax.device_put(buf)
 
 
 class StreamScan:
@@ -129,7 +129,7 @@ def _scan_fused(data: jax.Array, *, mask_s: int, mask_l: int):
     adds instead of 31, all uint32 wraparound, bit-identical to the
     serial gear recurrence past the 32B warm-up."""
     _TRACES.append(("scan", data.shape, mask_s, mask_l))
-    g = hashing.GEAR_TABLE_J[data.astype(jnp.int32)]
+    g = jnp.asarray(hashing.GEAR_TABLE)[data.astype(jnp.int32)]
     h = g
     m = data.shape[0]
     w = 1
@@ -157,17 +157,12 @@ def scan_stream(data: np.ndarray, mask_s: int, mask_l: int
     return StreamScan(h, n), cand_s, cand_l
 
 
-# Lmax is a gather extent (a shape), so it is a static argument like the
-# feature-config knobs.
-@functools.partial(jax.jit, static_argnames=("k", "n", "lmax", "normalize",
-                                             "use_kernel"))
-def _extract_fused(stream_hashes: jax.Array, offsets: jax.Array,
-                   lengths: jax.Array, a: jax.Array, b: jax.Array,
-                   *, k: int, n: int, lmax: int, normalize: bool,
-                   use_kernel: bool) -> jax.Array:
-    """[Spad] u32 hashes + [Bpad] offsets/lengths -> [Bpad, M] features."""
-    _TRACES.append((stream_hashes.shape, offsets.shape, lmax, k, n,
-                    normalize, use_kernel))
+def stream_subchunk_maxgear(stream_hashes: jax.Array, offsets: jax.Array,
+                            lengths: jax.Array, *, k: int, lmax: int
+                            ) -> jax.Array:
+    """[Spad] u32 hashes + [B] offsets/lengths -> [B, K] u32 sub-chunk
+    maxgear LSH: the integer stage of ``_extract_fused``, traced inside it
+    and bit-identical to ``features.subchunk_maxgear_np`` per chunk."""
     spad = stream_hashes.shape[0]
 
     # Sub-chunk maxgear LSH without scatter (XLA CPU scatter is serial and
@@ -222,6 +217,22 @@ def _extract_fused(stream_hashes: jax.Array, offsets: jax.Array,
                                       jnp.max(tail, axis=-1)))
         sub = sub.astype(jnp.uint32)
 
+    return sub
+
+
+# Lmax is a gather extent (a shape), so it is a static argument like the
+# feature-config knobs.
+@functools.partial(jax.jit, static_argnames=("k", "n", "lmax", "normalize",
+                                             "use_kernel"))
+def _extract_fused(stream_hashes: jax.Array, offsets: jax.Array,
+                   lengths: jax.Array, a: jax.Array, b: jax.Array,
+                   *, k: int, n: int, lmax: int, normalize: bool,
+                   use_kernel: bool) -> jax.Array:
+    """[Spad] u32 hashes + [Bpad] offsets/lengths -> [Bpad, M] features."""
+    _TRACES.append((stream_hashes.shape, offsets.shape, lmax, k, n,
+                    normalize, use_kernel))
+    sub = stream_subchunk_maxgear(stream_hashes, offsets, lengths,
+                                  k=k, lmax=lmax)
     ids = _feat.shingle_ids(sub, n)
     ids, mask = _feat.unique_mask(ids)
     if use_kernel:

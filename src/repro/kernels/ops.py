@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On TPU the kernels compile natively; everywhere else they run in
-interpret=True mode (the kernel body executed op-by-op), which is the
-validation mode this container exercises. `ref.py` holds the pure-jnp
-oracles used by tests and as large-input fallbacks.
+On TPU the kernels compile natively (Mosaic); on CPU, the test platform,
+they run in interpret=True mode (the kernel body executed op-by-op). No
+other platform has a path: it is an error, not a silent fallback.
+`ref.py` holds the pure-jnp oracles used by tests and as large-input
+fallbacks.
 """
 from __future__ import annotations
 
@@ -23,7 +24,11 @@ from repro.kernels import sim_topk as _topk
 def _interpret() -> bool:
     # cached: jax.default_backend() walks the backend registry on every
     # call, and this gates every kernel dispatch on the ingest hot path
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on tpu (compiled) or cpu "
+                           f"(interpret mode), not on {platform!r}")
+    return platform == "cpu"
 
 
 ROW_WIDTH = 8192
@@ -44,7 +49,7 @@ def _to_rows(stream: jax.Array, width: int = ROW_WIDTH) -> tuple[jax.Array, int]
 
 def gear_hashes(data: jax.Array) -> jax.Array:
     """[n] uint8 byte stream -> [n] uint32 windowed gear hashes."""
-    g = hashing.GEAR_TABLE_J[data.astype(jnp.int32)]
+    g = jnp.asarray(hashing.GEAR_TABLE)[data.astype(jnp.int32)]
     rows, n = _to_rows(g)
     weights = tuple(int(w) for w in hashing.GEAR_WEIGHTS)
     out = _gear.windowed_sum(rows, weights, interpret=_interpret())

@@ -17,19 +17,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    jax >= 0.5 grew ``jax.sharding.AxisType`` and ``make_mesh`` takes an
-    ``axis_types`` tuple; 0.4.x has neither. Everything in this repo (and
-    the subprocess scripts in tests) builds meshes through this shim so
-    the explicit-axis-type request is made exactly where it exists and
-    omitted where it would raise AttributeError/TypeError.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in Auto (GSPMD-propagated) mode,
+    the layout the sharding rules in ``repro.distributed`` assume."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -359,8 +359,6 @@ def moe(params, x, cfg):
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map
-
     m_ax = mesh.shape["model"]
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     dp = int(np.prod([mesh.shape[a] for a in batch_axes]))
@@ -411,8 +409,8 @@ def moe(params, x, cfg):
             pass
         return out.astype(x_loc.dtype).reshape(bl, sl, d), aux
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out, aux = fn(x, params["router"],
                   tuple(params[n] for n in enames))
     return constrain(out, "batch", "seq", "d_model"), aux

@@ -478,3 +478,50 @@ def test_banded_insert_batch_matches_serial_insert():
     qid_b, qs_b = b.query(feats[:8])
     assert np.array_equal(qid_a, qid_b)
     assert np.allclose(qs_a, qs_b)
+
+
+def _fresh_python(script, **env):
+    """Run ``script`` in a new interpreter; ``env`` entries override the
+    inherited environment, and a None value removes the variable."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    full = dict(os.environ, PYTHONPATH=str(src) + os.pathsep
+                + os.environ.get("PYTHONPATH", ""))
+    for k, v in env.items():
+        full.pop(k, None)
+        if v is not None:
+            full[k] = v
+    return subprocess.run([sys.executable, "-c", script], env=full,
+                          capture_output=True, text=True, timeout=120)
+
+
+def test_import_initialises_no_jax_backend():
+    """Importing the package must not take a device: a child process
+    that imports repro after its parent touched JAX would otherwise need
+    the chip the parent holds."""
+    p = _fresh_python(
+        "import repro.api, repro.core.pipeline, repro.kernels.ingest\n"
+        "from jax._src import xla_bridge\n"
+        "print(xla_bridge.backends_are_initialized())\n")
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == "False"
+
+
+def test_compile_cache_env_dir_wins_else_fixed_checkout_path(tmp_path):
+    import pathlib
+    script = ("import jax\nfrom repro import api\n"
+              "print(api.enable_compile_cache())\n"
+              "print(jax.config.jax_compilation_cache_dir)\n"
+              "print(jax.config.jax_persistent_cache_min_compile_time_secs)\n")
+    # every program is cached, however fast it compiled
+    p = _fresh_python(script, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.split() == [str(tmp_path)] * 2 + ["0"]
+
+    p = _fresh_python(script, JAX_COMPILATION_CACHE_DIR=None)
+    assert p.returncode == 0, p.stderr
+    fixed = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+    assert p.stdout.split() == [str(fixed)] * 2 + ["0"]

@@ -250,3 +250,58 @@ def test_streamscan_indexes_like_numpy():
     assert np.array_equal(np.asarray(scan), ref)
     assert np.array_equal(cand_s, (ref & np.uint32(0xFF)) == 0)
     assert np.array_equal(cand_l, (ref & np.uint32(0xF)) == 0)
+
+
+def _scan_and_extract(n=5000):
+    import jax.numpy as jnp
+    rng = np.random.Generator(np.random.PCG64(11))
+    data = rng.integers(0, 256, size=n, dtype=np.uint8)
+    scan, _, _ = ingest.scan_stream(data, 0xFF, 0xF)
+    offs = np.arange(0, n, 1000, dtype=np.int32)
+    lens = np.full(len(offs), 1000, np.int32)
+    a, b = (jnp.asarray(x) for x in hashing.multiply_shift_params(16))
+    feats = ingest._extract_fused(
+        scan.device, jnp.asarray(offs), jnp.asarray(lens), a, b,
+        k=8, n=2, lmax=1024, normalize=True, use_kernel=False)
+    return scan, feats
+
+
+def test_scan_and_extract_land_on_the_default_device():
+    """The staged stream goes to the default device, uncommitted, so the
+    scan and the extract run there: on a chip host, the chip. (Handing
+    the host buffer over by dlpack pinned both to the CPU.)"""
+    import jax
+    staged = ingest._stage(np.arange(100, dtype=np.uint8), 1 << 16)
+    assert not staged.committed
+    assert staged.devices() == {jax.devices()[0]}
+    scan, feats = _scan_and_extract()
+    dev = jax.devices()[0]
+    assert scan.device.devices() == {dev} and feats.devices() == {dev}
+    if dev.platform != "cpu":
+        assert not scan.device.committed and not feats.committed
+
+
+def test_scan_follows_default_device_choice():
+    """With two host devices and the second made the default, the scan
+    and extract must run on the second: a placement that ignored the
+    default device (dlpack returns arrays committed to CPU 0) fails."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    script = (
+        "import jax\n"
+        "import tests.test_ingest_fast as t\n"
+        "with jax.default_device(jax.devices()[1]):\n"
+        "    scan, feats = t._scan_and_extract()\n"
+        "print(sorted(d.id for d in scan.device.devices()),"
+        " sorted(d.id for d in feats.devices()))\n")
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ,
+               PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]),
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", script], env=env, cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == "[1] [1]"

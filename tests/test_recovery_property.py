@@ -130,7 +130,7 @@ def test_joint_truncation_seeded_sweep(reference, tmp_path):
     cuts = {(len(log), len(recipes)), (0, 0),
             (len(log), 0), (0, len(recipes))}
     # boundary-biased pairs: record/line edges are where off-by-ones live
-    edges_l = [0, 12, 13, 37, len(log) - 1, len(log)]
+    edges_l = [0, 1, 11, 12, 13, 37, len(log) - 1, len(log)]
     edges_r = [0, 1, len(recipes) - 1, len(recipes)]
     for el in edges_l:
         for er in edges_r:
