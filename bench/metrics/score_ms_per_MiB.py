@@ -1,0 +1,10 @@
+"""Milliseconds of the store's score stage per MiB committed, from the
+``IngestReport.score_seconds`` of the window's commits (host clock
+around a call that ends on the host)."""
+
+
+def read(run):
+    if not run.commits:
+        return None
+    mib = sum(r.bytes_in for r, _ in run.commits) / 2**20
+    return 1000.0 * sum(r.score_seconds for r, _ in run.commits) / mib
