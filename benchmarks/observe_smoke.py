@@ -113,7 +113,7 @@ def main() -> None:
         # --- trace ring + JSONL sink -------------------------------------
         ops = store.observe.tracer.ops()
         for op in ("ingest", "ingest.chunk", "ingest.store", "restore",
-                   "restore.read", "restore.decode", "restore.prefetch",
+                   "restore.plan", "restore.fetch", "restore.join",
                    "gc.delete", "gc.compact"):
             check(ops.get(op, 0) >= 1, f"no trace span for {op}")
         ring_count = len(store.observe.tracer.events())

@@ -28,10 +28,18 @@ returns the registry):
                       (``trace_ring_events``) and/or appended to a JSONL
                       file (``trace_path``), both ``DedupConfig`` knobs.
                       When neither knob is set a store has **no tracer
-                      at all** (``store.observe.tracer is None``), so
-                      the serving hot path pays a single ``is None``
-                      test — the ±15% warm-restore overhead guard in
-                      BENCH_RESTORE.json rides on that.
+                      at all** (``store.observe.tracer is None``) and
+                      books no events.
+    Span              the one span primitive: one pair of clock reads
+                      per span enters a ``jax.profiler.TraceAnnotation``
+                      named ``repro.<op>`` (a no-op unless a profiler
+                      session is active), books a ring/JSONL event when
+                      a tracer is in scope, and hands the duration back
+                      to the caller. Spans nest per thread: a child
+                      inherits its parent's tracer and id.
+    compile_tally     programs JAX traced, and their trace, lower and
+                      compile seconds, on the calling thread (one
+                      process-wide ``jax.monitoring`` listener).
 
 Two kinds of metric, one registry (the "no parallel bookkeeping" rule):
 
@@ -76,8 +84,9 @@ from typing import Any, Callable, Iterable, Sequence
 
 __all__ = [
     "BYTES_BUCKETS", "COUNT_BUCKETS", "DEFAULT_RING_EVENTS",
-    "SECONDS_BUCKETS", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Observability", "Tracer", "log2_bounds", "parse_prometheus_text",
+    "PROFILER_PREFIX", "SECONDS_BUCKETS", "Counter", "Gauge", "Histogram",
+    "MetricsRegistry", "Observability", "Span", "Tracer", "compile_tally",
+    "log2_bounds", "parse_prometheus_text",
 ]
 
 #: Ring size used when ``trace_path`` is set without ``trace_ring_events``.
@@ -570,10 +579,9 @@ def parse_prometheus_text(text: str) -> dict:
 
 
 class Tracer:
-    """Structured per-operation spans (module docstring). ``record``
-    books a completed operation retroactively (the instrumented code
-    already timed it); ``span`` is the context-manager form for code
-    that has no timer of its own. Events are plain dicts::
+    """Structured per-operation spans (module docstring). ``span`` times
+    a block (see ``Span``); ``record`` books an operation another timer
+    already measured. Events are plain dicts::
 
         {"op": str, "id": int, "parent": int|None, "tid": int,
          "t0": epoch-seconds, "s": duration-seconds, **labels}
@@ -597,12 +605,18 @@ class Tracer:
         """Book one completed span; returns its id (pass as ``parent``
         to attach stage children to an operation)."""
         span_id = next(self._ids)
+        self._book(op, span_id, parent,
+                   time.time() - seconds if t0 is None else t0, seconds,
+                   labels)
+        return span_id
+
+    def _book(self, op: str, span_id: int, parent: int | None, t0: float,
+              seconds: float, labels: dict) -> None:
         # structural fields win over same-named labels — a label called
         # "op" must not clobber the span's identity
         event = dict(labels)
         event.update({"op": op, "id": span_id, "parent": parent,
-                      "tid": threading.get_ident(),
-                      "t0": time.time() - seconds if t0 is None else t0,
+                      "tid": threading.get_ident(), "t0": t0,
                       "s": float(seconds)})
         ring = self._ring
         if ring is not None:
@@ -613,20 +627,17 @@ class Tracer:
             with self._wlock:
                 f.write(line + "\n")
                 f.flush()
-        return span_id
 
     @contextmanager
     def span(self, op: str, parent: int | None = None, **labels):
-        """Time a block as one span; the yielded dict is the label set
-        (mutate it to attach results discovered inside the block)."""
-        lbl = dict(labels)
-        t0 = time.time()
-        t0p = time.perf_counter()
-        try:
-            yield lbl
-        finally:
-            self.record(op, time.perf_counter() - t0p, t0=t0,
-                        parent=parent, **lbl)
+        """Time a block as one span of this tracer; the yielded dict is
+        the label set (mutate it to attach results discovered inside the
+        block). ``parent`` is a span id; by default the span nests under
+        the thread's open span, as a ``Span`` does."""
+        with Span(op, self, **labels) as sp:
+            if parent is not None:
+                sp.parent_id = parent
+            yield sp.labels
 
     def events(self) -> list[dict]:
         """Ring contents, oldest first (empty if no ring configured)."""
@@ -645,6 +656,158 @@ class Tracer:
         if f is not None:
             with self._wlock:
                 f.close()
+
+
+#: Prefix of every span's name in the profiler's trace.
+PROFILER_PREFIX = "repro."
+
+_local = threading.local()     # per thread: open spans, compile tallies
+_INHERIT = object()
+_annotation: Any = None
+
+
+def _open_spans() -> list:
+    stack = getattr(_local, "spans", None)
+    if stack is None:
+        stack = _local.spans = []
+    return stack
+
+
+def _trace_me(name: str):
+    global _annotation
+    if _annotation is None:
+        # imported on first use, so that importing repro.api stays free
+        # of JAX; importing the profiler starts no backend
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
+class Span:
+    """One timed operation. Entering reads ``time.time_ns()`` — the
+    ``CLOCK_REALTIME`` that the profiler's ``TraceMe`` stamps on Linux —
+    just after opening a ``TraceAnnotation`` named ``repro.<op>``;
+    leaving reads it once more. ``seconds`` is then the duration, and
+    ``children`` the closed spans opened under this one, so a caller
+    reads a nested stage's time, or places it on the profiler's clock,
+    without a timer of its own.
+
+    The tracer is the one given, else the parent's: a span opened with
+    no tracer in scope books nothing, and still annotates the profiler's
+    trace and measures. An operation's root passes its store's tracer,
+    ``None`` included, so that it never books into another store's. The
+    parent is the thread's innermost open span unless one is given (a
+    span opened on a pool thread for a request names its request).
+    ``start()`` opens a span that is neither pushed on the thread's
+    stack nor annotated, for an operation whose extent is not one block
+    of one thread (an iterator consumed over many resumptions).
+    ``end()`` closes either kind, once."""
+
+    __slots__ = ("op", "labels", "tracer", "id", "parent_id", "t0_ns",
+                 "seconds", "children", "_parent", "_me", "_open")
+
+    def __init__(self, op: str, tracer: Any = _INHERIT,
+                 parent: "Span | None" = None, **labels) -> None:
+        self.op = op
+        self.labels = labels
+        self.tracer = tracer
+        self.id = self.parent_id = None
+        self.t0_ns = 0
+        self.seconds = 0.0
+        self.children: list[Span] = []
+        self._parent = parent
+        self._me = None
+        self._open = False
+
+    def _begin(self, parent: "Span | None") -> None:
+        tracer = self.tracer
+        if tracer is _INHERIT:
+            tracer = self.tracer = parent.tracer if parent else None
+        self._parent = parent
+        if tracer is not None:
+            self.id = next(tracer._ids)
+            if parent is not None and parent.tracer is tracer:
+                self.parent_id = parent.id
+        self._open = True
+
+    def start(self) -> "Span":
+        self._begin(self._parent)
+        self.t0_ns = time.time_ns()
+        return self
+
+    def __enter__(self) -> "Span":
+        stack = _open_spans()
+        self._begin(self._parent or (stack[-1] if stack else None))
+        stack.append(self)
+        self._me = _trace_me(PROFILER_PREFIX + self.op)
+        self._me.__enter__()
+        self.t0_ns = time.time_ns()
+        return self
+
+    def end(self, error: BaseException | None = None) -> None:
+        if not self._open:
+            return
+        t1 = time.time_ns()
+        self._open = False
+        if self._me is not None:
+            self._me.__exit__(None, None, None)
+            _open_spans().remove(self)
+        self.seconds = (t1 - self.t0_ns) / 1e9
+        if self._parent is not None:
+            self._parent.children.append(self)
+        if self.tracer is not None:
+            if error is not None:
+                self.labels["error"] = type(error).__name__
+            self.tracer._book(self.op, self.id, self.parent_id,
+                              self.t0_ns / 1e9, self.seconds, self.labels)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end(exc)
+
+    def walk(self):
+        """This span, then its closed descendants, depth first."""
+        yield self
+        for child in self.children:
+            yield from child.walk()
+
+    def child_seconds(self, op: str) -> float:
+        """Summed ``seconds`` of the closed descendants named ``op``."""
+        return sum(s.seconds for s in self.walk() if s.op == op)
+
+
+_COMPILE_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration"))
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def _on_compile_event(event: str, duration_secs: float, **_) -> None:
+    if event in _COMPILE_EVENTS:
+        tally = getattr(_local, "compiles", None)
+        if tally is None:
+            tally = _local.compiles = [0, 0.0]
+        tally[0] += event == _TRACE_EVENT
+        tally[1] += duration_secs
+
+
+def compile_tally() -> tuple[int, float]:
+    """``(programs traced, trace + lower + compile seconds)`` on the
+    calling thread since the process's first call; diff two readings to
+    count a span's compiles. JAX reports these on the thread that
+    compiles, so concurrent threads never count each other's."""
+    global _listening
+    if not _listening:
+        with _listen_lock:
+            if not _listening:
+                import jax.monitoring
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile_event)
+                _listening = True
+    tally = getattr(_local, "compiles", None)
+    return (tally[0], tally[1]) if tally else (0, 0.0)
 
 
 class Observability:

@@ -236,8 +236,14 @@ class DedupStore:
             s: m.histogram("repro_ingest_stage_seconds",
                            "Per-commit ingest phase timings (§8)",
                            labels={"stage": s}, bounds=om.SECONDS_BUCKETS)
-            for s in ("chunk", "extract", "score", "observe", "delta",
-                      "store")}
+            for s in ("chunk", "dedup", "extract", "score", "search",
+                      "observe", "base_read", "delta", "store")}
+        self._c_jax_compiles = m.counter(
+            "repro_jax_compiles_total",
+            "Programs JAX traced on the committing thread during commits")
+        self._c_jax_compile_seconds = m.counter(
+            "repro_jax_compile_seconds_total",
+            "Trace, lower and compile seconds of those programs")
         self._c_restore_ops = {
             s: m.counter("repro_restore_ops_total",
                          "Restore calls by serving surface (§9)",
@@ -249,9 +255,9 @@ class DedupStore:
                          labels={"dir": d}) for d in ("out", "read")}
         self._h_restore_stage = {
             s: m.histogram("repro_restore_stage_seconds",
-                           "Per-restore wall/read/decode timings (§9)",
+                           "Per-restore wall/read/decode/join timings (§9)",
                            labels={"stage": s}, bounds=om.SECONDS_BUCKETS)
-            for s in ("total", "read", "decode")}
+            for s in ("total", "read", "decode", "join")}
         self._h_restore_requests = m.histogram(
             "repro_restore_requests",
             "Physical payload reads (preads / ranged GETs) per restore",
@@ -346,48 +352,59 @@ class DedupStore:
         # are excluded from lifecycle mutations (DESIGN.md §10.4).
         # Under a deadline scope (§15.3) both lock waits are bounded:
         # shedding here — before any chunking work — is the cheap place.
-        check_deadline("commit")
-        t = remaining_time()
-        if t is None:
-            self._commit_lock.acquire()
-        elif not self._commit_lock.acquire(timeout=max(0.0, t)):
-            raise DeadlineExceededError("commit (commit-lock wait)")
-        try:
-            self._acquire_read_deadline("commit")
+        # The root span holds the lock waits; it ends, inside the locks,
+        # just before the report is built from it.
+        from repro.api import observe as om
+        compiles = om.compile_tally()
+        with om.Span("ingest", self.observe.tracer) as root:
+            check_deadline("commit")
+            t = remaining_time()
+            if t is None:
+                self._commit_lock.acquire()
+            elif not self._commit_lock.acquire(timeout=max(0.0, t)):
+                raise DeadlineExceededError("commit (commit-lock wait)")
             try:
-                # post-close contract: fail here, before the chunk/detect
-                # passes run, instead of dying on the closed append handle
-                # after the work is done
-                self._check_open()
-                return self._commit_stream_locked(stream)
+                self._acquire_read_deadline("commit")
+                try:
+                    # post-close contract: fail here, before the chunk/
+                    # detect passes run, instead of dying on the closed
+                    # append handle after the work is done
+                    self._check_open()
+                    return self._commit_stream_locked(stream, root,
+                                                      compiles)
+                finally:
+                    self._lifecycle_lock.release_read()
             finally:
-                self._lifecycle_lock.release_read()
-        finally:
-            self._commit_lock.release()
+                self._commit_lock.release()
 
-    def _commit_stream_locked(self, stream: bytes) -> IngestReport:
-        # pass 0: chunk
-        t0 = time.perf_counter()
-        chunks, stream_hashes = chunk_with(self.cfg, stream)
-        chunk_seconds = time.perf_counter() - t0
+    def _commit_stream_locked(self, stream: bytes, root: Any,
+                              compiles: tuple[int, float]) -> IngestReport:
+        from repro.api import observe as om
+        # each pass is one span; a per-chunk stage (base reads, encodes)
+        # is timed by accumulated sums inside its pass's span
+        with om.Span("ingest.chunk") as sp:
+            chunks, stream_hashes = chunk_with(self.cfg, stream)
+        chunk_seconds = sp.seconds
 
         # pass 1: exact dedup; assign ids
-        n = len(chunks)
-        ids = np.empty(n, np.int64)
-        is_new = np.zeros(n, bool)
-        digests = [ck.digest for ck in chunks]
-        seen_in_stream: dict[bytes, int] = {}
-        for i, dig in enumerate(digests):
-            ref = self._by_digest.get(dig)
-            if ref is None:
-                ref = seen_in_stream.get(dig)
-            if ref is not None:
-                ids[i] = ref
-            else:
-                ids[i] = self._next_id
-                self._next_id += 1
-                is_new[i] = True
-                seen_in_stream[dig] = int(ids[i])
+        with om.Span("ingest.dedup") as sp:
+            n = len(chunks)
+            ids = np.empty(n, np.int64)
+            is_new = np.zeros(n, bool)
+            digests = [ck.digest for ck in chunks]
+            seen_in_stream: dict[bytes, int] = {}
+            for i, dig in enumerate(digests):
+                ref = self._by_digest.get(dig)
+                if ref is None:
+                    ref = seen_in_stream.get(dig)
+                if ref is not None:
+                    ids[i] = ref
+                else:
+                    ids[i] = self._next_id
+                    self._next_id += 1
+                    is_new[i] = True
+                    seen_in_stream[dig] = int(ids[i])
+        dedup_seconds = sp.seconds
 
         # deadline probes (§15.3) run only in passes 0-3a — after the
         # first pass-3b backend write the commit must finish (aborting
@@ -402,26 +419,29 @@ class DedupStore:
         # detectors mutate inside detect() and can't make that promise.
         # A zero-chunk stream (``ingest(b"")``) never reaches a detector
         # at all — neither path is required to accept an empty batch.
+        # The detector's index query is the ``ingest.search`` child of
+        # the score span.
         extract_seconds = score_seconds = observe_seconds = 0.0
+        search_seconds = 0.0
         batch = DetectBatch(chunks=chunks, ids=ids, is_new=is_new,
                             stream_hashes=stream_hashes)
         staged = n > 0 and is_staged(self.detector)
         feats = None
         if n == 0:
             base_ids = np.empty(0, np.int64)
-        elif staged:
-            t0 = time.perf_counter()
-            feats = self.detector.extract(batch)
-            extract_seconds = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            base_ids = self.detector.score(feats, batch).base_ids
-            score_seconds = time.perf_counter() - t0
         else:
-            t0 = time.perf_counter()
-            base_ids = np.asarray(
-                self.detector.detect(chunks, ids, is_new, stream_hashes),
-                np.int64)
-            score_seconds = time.perf_counter() - t0
+            if staged:
+                with om.Span("ingest.extract") as sp:
+                    feats = self.detector.extract(batch)
+                extract_seconds = sp.seconds
+            with om.Span("ingest.score") as sp:
+                if staged:
+                    base_ids = self.detector.score(feats, batch).base_ids
+                else:
+                    base_ids = np.asarray(self.detector.detect(
+                        chunks, ids, is_new, stream_hashes), np.int64)
+            score_seconds = sp.seconds
+            search_seconds = sp.child_seconds("ingest.search")
 
         # pass 3a: delta-vs-raw decisions over a worklist — every
         # delta.encode runs here, back to back, with no backend I/O
@@ -437,70 +457,88 @@ class DedupStore:
         overhead = int(getattr(backend, "record_overhead", 0))
         dup_chunks = int(n - is_new.sum())
         delta_chunks = raw_chunks = 0
-        delta_seconds = 0.0
+        delta_seconds = base_read_seconds = 0.0
+        base_reads = base_read_hits = 0
         staged_data: dict[int, bytes] = {}
         records: list[tuple[int, int, bytes, bytes | None]] = []
         check_deadline("commit")
-        for i in np.flatnonzero(is_new):
-            check_deadline("commit")    # last shed point: nothing written yet
-            ck = chunks[i]
-            cid = int(ids[i])
-            entry = None
-            base = int(base_ids[i])
-            if base >= 0:
-                base_data = staged_data.get(base)
-                if base_data is None and backend.contains(base):
-                    base_data = backend.get(base)
-                if base_data is not None:
+        with om.Span("ingest.delta"):
+            for i in np.flatnonzero(is_new):
+                # last shed point: nothing written yet
+                check_deadline("commit")
+                ck = chunks[i]
+                cid = int(ids[i])
+                entry = None
+                base = int(base_ids[i])
+                if base >= 0:
+                    # the base: this commit's own chunk, else a read of
+                    # the store (a delta chain walked through the cache)
                     t0 = time.perf_counter()
-                    d = delta.encode(ck.data, base_data)
-                    delta_seconds += time.perf_counter() - t0
-                    if len(d) < ck.length:
-                        entry = (cid, base, d, ck.data)
-                        bytes_stored += len(d) + overhead
-                        delta_chunks += 1
-            if entry is None:
-                entry = (cid, -1, ck.data, None)
-                bytes_stored += ck.length + overhead
-                raw_chunks += 1
-            records.append(entry)
-            staged_data[cid] = ck.data
+                    base_data = staged_data.get(base)
+                    if base_data is not None:
+                        base_read_hits += 1
+                    elif backend.contains(base):
+                        base_data = backend.get(base)
+                    base_read_seconds += time.perf_counter() - t0
+                    base_reads += 1
+                    if base_data is not None:
+                        t0 = time.perf_counter()
+                        d = delta.encode(ck.data, base_data)
+                        delta_seconds += time.perf_counter() - t0
+                        if len(d) < ck.length:
+                            entry = (cid, base, d, ck.data)
+                            bytes_stored += len(d) + overhead
+                            delta_chunks += 1
+                if entry is None:
+                    entry = (cid, -1, ck.data, None)
+                    bytes_stored += ck.length + overhead
+                    raw_chunks += 1
+                records.append(entry)
+                staged_data[cid] = ck.data
 
         # pass 3b: one batched backend write + recipe + flush (group
         # commit: a stream is a single buffered append, DESIGN.md §8).
         # Refcount/digest bookkeeping happens only after the writes
         # succeed, so a failed commit cannot leave digests pointing at
         # payloads that were never stored.
-        t0 = time.perf_counter()
-        put_many = getattr(backend, "put_many", None)
-        if put_many is not None:
-            put_many(records)
-        else:                       # third-party backends: per-chunk puts
-            for cid, base, payload, data in records:
-                if base < 0:
-                    backend.put_raw(cid, payload)
-                else:
-                    backend.put_delta(cid, base, payload, data=data)
-        for i, (cid, base, payload, _) in zip(np.flatnonzero(is_new),
-                                              records):
-            self._refs.track(cid, base, len(payload))
-            self._by_digest[digests[i]] = cid
-        recipe = [int(c) for c in ids]
-        if self._recipe_lengths_ok:     # persist materialized lengths
-            handle = backend.add_recipe(recipe,     # for ranged restores
-                                        [int(ck.length) for ck in chunks])
-        else:                           # pre-§9 backend signature
-            handle = backend.add_recipe(recipe)
-        for cid in recipe:      # only now do the chunks become live
-            self._refs.incref_recipe(cid)
-        backend.flush()
-        store_seconds = time.perf_counter() - t0
+        with om.Span("ingest.store") as sp:
+            put_many = getattr(backend, "put_many", None)
+            if put_many is not None:
+                put_many(records)
+            else:                   # third-party backends: per-chunk puts
+                for cid, base, payload, data in records:
+                    if base < 0:
+                        backend.put_raw(cid, payload)
+                    else:
+                        backend.put_delta(cid, base, payload, data=data)
+            for i, (cid, base, payload, _) in zip(np.flatnonzero(is_new),
+                                                  records):
+                self._refs.track(cid, base, len(payload))
+                self._by_digest[digests[i]] = cid
+            recipe = [int(c) for c in ids]
+            if self._recipe_lengths_ok:     # persist materialized lengths
+                handle = backend.add_recipe(    # for ranged restores
+                    recipe, [int(ck.length) for ck in chunks])
+            else:                           # pre-§9 backend signature
+                handle = backend.add_recipe(recipe)
+            for cid in recipe:      # only now do the chunks become live
+                self._refs.incref_recipe(cid)
+            backend.flush()
+        store_seconds = sp.seconds
 
         if staged:
-            t0 = time.perf_counter()
-            self.detector.observe(feats, batch)
-            observe_seconds = time.perf_counter() - t0
+            with om.Span("ingest.observe") as sp:
+                self.detector.observe(feats, batch)
+            observe_seconds = sp.seconds
 
+        if root.tracer is not None:
+            root.labels.update(
+                handle=handle, bytes_in=bytes_in, bytes_stored=bytes_stored,
+                chunks=n, dup_chunks=dup_chunks, delta_chunks=delta_chunks,
+                base_reads=base_reads, base_read_hits=base_read_hits,
+                dcr=round(bytes_in / max(1, bytes_stored), 4))
+        root.end()
+        traced, compile_seconds = om.compile_tally()
         report = IngestReport(
             handle=handle, bytes_in=bytes_in, bytes_stored=bytes_stored,
             chunks=n, dup_chunks=dup_chunks, delta_chunks=delta_chunks,
@@ -508,7 +546,13 @@ class DedupStore:
             detect_seconds=extract_seconds + score_seconds + observe_seconds,
             chunk_seconds=chunk_seconds, delta_seconds=delta_seconds,
             extract_seconds=extract_seconds, score_seconds=score_seconds,
-            observe_seconds=observe_seconds, store_seconds=store_seconds)
+            observe_seconds=observe_seconds, store_seconds=store_seconds,
+            dedup_seconds=dedup_seconds, search_seconds=search_seconds,
+            base_read_seconds=base_read_seconds, base_reads=base_reads,
+            base_read_hits=base_read_hits, commit_seconds=root.seconds,
+            compiles=traced - compiles[0],
+            compile_seconds=compile_seconds - compiles[1],
+            spans=tuple((s.op, s.t0_ns, s.seconds) for s in root.walk()))
         with self._stats_lock:
             self.reports.append(report)
             self.stats.absorb(report)
@@ -517,32 +561,25 @@ class DedupStore:
         return report
 
     def _observe_ingest(self, r: IngestReport) -> None:
-        """Record one commit into the registry (and ring, when tracing):
-        the stage timings the report already measured — no new timers on
-        the ingest path (DESIGN.md §12.3)."""
+        """Record one commit into the registry: counters and the stage
+        timings its spans measured (DESIGN.md §12.3)."""
         self._c_ingest_commits.inc()
         self._c_ingest_bytes["in"].inc(r.bytes_in)
         self._c_ingest_bytes["stored"].inc(r.bytes_stored)
         self._c_ingest_chunks["dup"].inc(r.dup_chunks)
         self._c_ingest_chunks["delta"].inc(r.delta_chunks)
         self._c_ingest_chunks["raw"].inc(r.raw_chunks)
-        stages = (("chunk", r.chunk_seconds), ("extract", r.extract_seconds),
-                  ("score", r.score_seconds), ("observe", r.observe_seconds),
-                  ("delta", r.delta_seconds), ("store", r.store_seconds))
-        for stage, seconds in stages:
-            self._h_ingest_stage[stage].observe(seconds)
-        tr = self.observe.tracer
-        if tr is not None:
-            total = sum(s for _, s in stages)
-            pid = tr.record("ingest", total, handle=r.handle,
-                            bytes_in=r.bytes_in, bytes_stored=r.bytes_stored,
-                            chunks=r.chunks, dup_chunks=r.dup_chunks,
-                            delta_chunks=r.delta_chunks,
-                            dcr=round(r.dcr, 4))
-            t0 = time.time() - total
-            for stage, seconds in stages:
-                tr.record("ingest." + stage, seconds, t0=t0, parent=pid)
-                t0 += seconds
+        self._c_jax_compiles.inc(r.compiles)
+        self._c_jax_compile_seconds.inc(r.compile_seconds)
+        h = self._h_ingest_stage
+        for stage, seconds in (
+                ("chunk", r.chunk_seconds), ("dedup", r.dedup_seconds),
+                ("extract", r.extract_seconds), ("score", r.score_seconds),
+                ("search", r.search_seconds),
+                ("observe", r.observe_seconds),
+                ("base_read", r.base_read_seconds),
+                ("delta", r.delta_seconds), ("store", r.store_seconds)):
+            h[stage].observe(seconds)
 
     # --- serving path (repro.api.restore, DESIGN.md §9) ----------------------
 
@@ -551,12 +588,16 @@ class DedupStore:
         Raises KeyError once the stream has been deleted (IndexError for
         a handle the store never issued). Safe to call from any number
         of threads at once (DESIGN.md §10.4)."""
-        recipe = self.backend.recipe(handle)
-        t0 = time.perf_counter()
-        data, d = self._fetch_counted(recipe)
-        out = b"".join(data[cid] for cid in recipe)
-        self._note_restore(handle, len(out), len(recipe),
-                           time.perf_counter() - t0, d, surface="full")
+        from repro.api import observe as om
+        with om.Span("restore", self.observe.tracer, surface="full",
+                     handle=handle) as root:
+            with om.Span("restore.plan"):
+                recipe = self.backend.recipe(handle)
+            data, d = self._fetch_counted(recipe)
+            with om.Span("restore.join") as sp:
+                out = b"".join(data[cid] for cid in recipe)
+            self._note_restore(root, handle, len(out), len(recipe),
+                               sp.seconds, d, surface="full")
         return out
 
     def restore_iter(self, handle: int, batch_chunks: int = 256):
@@ -569,11 +610,21 @@ class DedupStore:
         batch *k*, batch *k+1* is already being fetched on the prefetch
         pool (DESIGN.md §10.3), so I/O, decode and consumer work
         overlap. Same errors as ``restore``, raised at call time; the
-        ``RestoreReport`` is recorded when the iterator is exhausted."""
-        recipe = self.backend.recipe(handle)    # raise before iterating
+        ``RestoreReport`` is recorded when the iterator is exhausted.
+        Its root span runs from the call to exhaustion and is booked
+        in the tracer only: a span held across ``yield`` would enclose
+        the consumer's work in the profiler's trace."""
+        from repro.api import observe as om
+        root = om.Span("restore", self.observe.tracer, surface="iter",
+                       handle=handle).start()
+        try:
+            with om.Span("restore.plan", parent=root):
+                recipe = self.backend.recipe(handle)    # raise at call time
+        except Exception as e:
+            root.end(e)
+            raise
 
         def gen():
-            t0 = time.perf_counter()
             acc = zero_deltas()
             total = 0
             fut = None
@@ -584,12 +635,12 @@ class DedupStore:
                         data, d = fut.result()
                         fut = None
                     else:
-                        data, d = self._fetch_counted(part)
+                        data, d = self._fetch_counted(part, root)
                     accumulate(acc, d)
                     nxt = recipe[i + batch_chunks:i + 2 * batch_chunks]
                     if nxt:     # overlap the next fetch with consumption
                         fut = self._prefetch_pool().submit(
-                            self._prefetch_fetch, nxt)
+                            self._prefetch_fetch, nxt, root)
                     for cid in part:
                         piece = data[cid]
                         total += len(piece)
@@ -597,8 +648,7 @@ class DedupStore:
             finally:
                 if fut is not None:     # abandoned mid-stream
                     fut.cancel()
-            self._note_restore(handle, total, len(recipe),
-                               time.perf_counter() - t0, acc,
+            self._note_restore(root, handle, total, len(recipe), 0.0, acc,
                                surface="iter")
 
         return gen()
@@ -610,22 +660,26 @@ class DedupStore:
         overlapping the range are read and chain-decoded. Ranges are
         clamped to the stream tail; negative offset/length raise
         ValueError; same handle errors as ``restore``."""
-        recipe = self.backend.recipe(handle)
-        t0 = time.perf_counter()
-        acc = zero_deltas()
-        first, last, skip = self._layout(handle, recipe, acc).chunk_window(
-            offset, length)
-        if last < first:
-            self._note_restore(handle, 0, 0, time.perf_counter() - t0, acc,
-                               surface="range")
-            return b""
-        part = recipe[first:last + 1]
-        data, d = self._fetch_counted(part)
-        accumulate(acc, d)
-        blob = b"".join(data[cid] for cid in part)
-        out = blob[skip:skip + min(length, len(blob) - skip)]
-        self._note_restore(handle, len(out), len(part),
-                           time.perf_counter() - t0, acc, surface="range")
+        from repro.api import observe as om
+        with om.Span("restore", self.observe.tracer, surface="range",
+                     handle=handle) as root:
+            acc = zero_deltas()
+            with om.Span("restore.plan"):
+                recipe = self.backend.recipe(handle)
+                first, last, skip = self._layout(
+                    handle, recipe, acc).chunk_window(offset, length)
+            if last < first:
+                self._note_restore(root, handle, 0, 0, 0.0, acc,
+                                   surface="range")
+                return b""
+            part = recipe[first:last + 1]
+            data, d = self._fetch_counted(part)
+            accumulate(acc, d)
+            with om.Span("restore.join") as sp:
+                blob = b"".join(data[cid] for cid in part)
+                out = blob[skip:skip + min(length, len(blob) - skip)]
+            self._note_restore(root, handle, len(out), len(part),
+                               sp.seconds, acc, surface="range")
         return out
 
     def stream_length(self, handle: int) -> int:
@@ -685,30 +739,43 @@ class DedupStore:
         except LockTimeout as e:
             raise DeadlineExceededError(f"{op} (lifecycle-lock wait)") from e
 
-    def _fetch_counted(self, cids: Sequence[int]) -> tuple[dict, list]:
+    def _fetch_counted(self, cids: Sequence[int],
+                       request: Any = None) -> tuple[dict, list]:
         """``_fetch_unique`` under the shared lifecycle lock, returning
         ``(data, io_counter_deltas)``. The snapshot pair runs on the
         same thread as the fetch (see ``FileBackend.io_counters``), so
         the deltas are exact per call even with other restores in
-        flight — including when this runs on the prefetch pool."""
+        flight — including when this runs on the prefetch pool. One
+        ``restore.fetch`` span, under ``request`` where given (a pool
+        thread has no open span of its request), else under the
+        thread's open span."""
+        from repro.api import observe as om
         lock = self._lifecycle_lock
-        check_deadline("restore")
-        snap = self._backend_counters()
-        self._acquire_read_deadline("restore")
-        try:
-            # a resumed restore_iter generator can arrive here after
-            # close(): the backend's reader fds are gone, so fail with a
-            # clean error instead of whatever the closed backend raises.
-            # The flag flips under the write lock, so a reader seeing it
-            # False is ordered before the close and fetches safely.
-            self._check_open()
-            data = self._fetch_unique(cids)
-        finally:
-            lock.release_read()
-        now = self._backend_counters()
-        return data, [now[i] - snap[i] for i in range(len(snap))]
+        with om.Span("restore.fetch", parent=request) as sp:
+            check_deadline("restore")
+            snap = self._backend_counters()
+            self._acquire_read_deadline("restore")
+            try:
+                # a resumed restore_iter generator can arrive here after
+                # close(): the backend's reader fds are gone, so fail
+                # with a clean error instead of whatever the closed
+                # backend raises. The flag flips under the write lock,
+                # so a reader seeing it False is ordered before the
+                # close and fetches safely.
+                self._check_open()
+                data = self._fetch_unique(cids)
+            finally:
+                lock.release_read()
+            now = self._backend_counters()
+            d = [now[i] - snap[i] for i in range(len(snap))]
+            if sp.tracer is not None:
+                sp.labels.update(chunks=len(cids), read_s=d[0],
+                                 decode_s=d[1], cache_hits=d[3],
+                                 cache_misses=d[4])
+        return data, d
 
-    def _prefetch_fetch(self, cids: Sequence[int]) -> tuple[dict, list]:
+    def _prefetch_fetch(self, cids: Sequence[int],
+                        request: Any = None) -> tuple[dict, list]:
         """``_fetch_counted`` as a prefetch-pool task: folds this pool
         thread's telemetry record and metric shard when the task is
         done. Pool threads live as long as the store, so without the
@@ -718,7 +785,7 @@ class DedupStore:
         Folding happens after the counter snapshot pair, so the per-call
         deltas the caller consumes are unaffected."""
         try:
-            return self._fetch_counted(cids)
+            return self._fetch_counted(cids, request)
         finally:
             fold = self._fold_io
             if fold is not None:
@@ -790,45 +857,39 @@ class DedupStore:
                 getattr(b, "prefetch_bytes", 0),
                 getattr(b, "read_requests", 0))
 
-    def _note_restore(self, handle: int, bytes_out: int, chunks: int,
-                      seconds: float, d: Sequence,
+    def _note_restore(self, root: Any, handle: int, bytes_out: int,
+                      chunks: int, join_seconds: float, d: Sequence,
                       surface: str = "full") -> None:
+        """End the request's root span and record its report: wall time
+        from the span, read/decode/cache counters from the fetches'
+        telemetry deltas, the answer's assembly from its join span."""
+        hits, misses = int(d[3]), int(d[4])
+        if root.tracer is not None:
+            root.labels.update(
+                bytes_out=bytes_out, bytes_read=int(d[2]),
+                requests=int(d[6]), cache_hits=hits, cache_misses=misses,
+                hit_ratio=round(hits / max(1, hits + misses), 4),
+                prefetch_bytes=int(d[5]))
+        root.end()
         report = RestoreReport(
             handle=handle, bytes_out=bytes_out, chunks=chunks,
-            seconds=seconds,
+            seconds=root.seconds,
             read_seconds=d[0], decode_seconds=d[1], bytes_read=int(d[2]),
-            cache_hits=int(d[3]), cache_misses=int(d[4]),
-            prefetch_bytes=int(d[5]), requests=int(d[6]))
+            cache_hits=hits, cache_misses=misses,
+            prefetch_bytes=int(d[5]), requests=int(d[6]),
+            join_seconds=join_seconds)
         with self._stats_lock:
             self.last_restore = report
             self.stats.absorb_restore(report)
         self._c_restore_ops[surface].inc()
         self._c_restore_bytes["out"].inc(report.bytes_out)
         self._c_restore_bytes["read"].inc(report.bytes_read)
-        self._h_restore_stage["total"].observe(seconds)
-        self._h_restore_stage["read"].observe(report.read_seconds)
-        self._h_restore_stage["decode"].observe(report.decode_seconds)
+        h = self._h_restore_stage
+        h["total"].observe(report.seconds)
+        h["read"].observe(report.read_seconds)
+        h["decode"].observe(report.decode_seconds)
+        h["join"].observe(join_seconds)
         self._h_restore_requests.observe(report.requests)
-        tr = self.observe.tracer
-        if tr is not None:
-            hits, misses = report.cache_hits, report.cache_misses
-            pid = tr.record(
-                "restore", seconds, surface=surface, handle=handle,
-                bytes_out=report.bytes_out, bytes_read=report.bytes_read,
-                requests=report.requests, cache_hits=hits,
-                cache_misses=misses,
-                hit_ratio=round(hits / max(1, hits + misses), 4))
-            t0 = time.time() - seconds
-            tr.record("restore.plan", max(
-                0.0, seconds - report.read_seconds - report.decode_seconds),
-                t0=t0, parent=pid, chunks=chunks)
-            tr.record("restore.read", report.read_seconds, t0=t0,
-                      parent=pid, bytes_read=report.bytes_read,
-                      requests=report.requests)
-            tr.record("restore.decode", report.decode_seconds, t0=t0,
-                      parent=pid)
-            tr.record("restore.prefetch", 0.0, t0=t0, parent=pid,
-                      prefetch_bytes=report.prefetch_bytes)
 
     # --- space reclamation (repro.api.lifecycle, DESIGN.md §7) ---------------
 

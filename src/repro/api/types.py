@@ -109,6 +109,25 @@ class IngestReport:
     score_seconds: float = 0.0
     observe_seconds: float = 0.0
     store_seconds: float = 0.0
+    # measured by the commit's spans (DESIGN.md §12.3): pass 1's digest
+    # lookups and id assignment; the index query inside score_seconds;
+    # pass 3a's base lookups outside delta_seconds, with their count and
+    # how many of them were this commit's own chunks (no store read);
+    # the whole commit, lock waits included
+    dedup_seconds: float = 0.0
+    search_seconds: float = 0.0
+    base_read_seconds: float = 0.0
+    base_reads: int = 0
+    base_read_hits: int = 0
+    commit_seconds: float = 0.0
+    # programs JAX traced on the committing thread during the commit, and
+    # their trace + lower + compile seconds
+    compiles: int = 0
+    compile_seconds: float = 0.0
+    # (op, start ns, seconds) of the commit's spans, root first, then
+    # depth first; starts are ``time.time_ns()``, the profiler's clock,
+    # so a caller with no tracer can place each stage on a device trace
+    spans: tuple = dataclasses.field(default=(), repr=False)
 
     @property
     def dcr(self) -> float:
@@ -127,7 +146,7 @@ class RestoreReport:
     handle: int
     bytes_out: int = 0          # bytes served to the caller
     chunks: int = 0             # recipe slots touched
-    seconds: float = 0.0        # end-to-end wall time
+    seconds: float = 0.0        # end-to-end wall time of the call
     read_seconds: float = 0.0   # container payload I/O (summed across
     #                             pooled readers, so it can exceed the
     #                             wall-clock share once readahead overlaps
@@ -142,6 +161,7 @@ class RestoreReport:
     # physical payload reads issued (preads / ranged GETs): the cost
     # metric for latency-bound remote backends (DESIGN.md §11.3)
     requests: int = 0
+    join_seconds: float = 0.0   # assembling and slicing the answer
 
     @property
     def read_amplification(self) -> float:
@@ -193,6 +213,7 @@ class StoreStats:
     restore_cache_misses: int = 0
     restore_prefetch_bytes: int = 0
     restore_requests: int = 0
+    restore_join_seconds: float = 0.0
 
     @property
     def dcr(self) -> float:
@@ -224,3 +245,4 @@ class StoreStats:
         self.restore_cache_misses += report.cache_misses
         self.restore_prefetch_bytes += report.prefetch_bytes
         self.restore_requests += report.requests
+        self.restore_join_seconds += report.join_seconds

@@ -196,11 +196,14 @@ class CARDDetector(LegacyDetectMixin):
         return self.model.transform(init)[:n]                 # [n, D]
 
     def score(self, feats: np.ndarray, batch: DetectBatch) -> DetectResult:
+        from repro.api import observe   # off the package-import path
         n = len(batch)
         out = np.full(n, -1, np.int64)
 
-        # phase 1: against the stored index
-        ext_ids, ext_scores = self.index.query(feats)
+        # phase 1: against the stored index (the index's copy to the
+        # device, the sim_topk call and its compile, the answer's fetch)
+        with observe.Span("ingest.search"):
+            ext_ids, ext_scores = self.index.query(feats)
 
         # phase 2: intra-stream (earlier chunks of this stream)
         sims = feats @ feats.T

@@ -129,16 +129,20 @@ def _scan_fused(data: jax.Array, *, mask_s: int, mask_l: int):
     adds instead of 31, all uint32 wraparound, bit-identical to the
     serial gear recurrence past the 32B warm-up."""
     _TRACES.append(("scan", data.shape, mask_s, mask_l))
-    g = jnp.asarray(hashing.GEAR_TABLE)[data.astype(jnp.int32)]
-    h = g
-    m = data.shape[0]
-    w = 1
-    while w < hashing.GEAR_WINDOW:
-        shifted = jnp.concatenate([jnp.zeros(w, jnp.uint32), h[:m - w]])
-        h = h + shifted * jnp.uint32((1 << w) & 0xFFFFFFFF)
-        w *= 2
-    cand_s = jnp.packbits((h & jnp.uint32(mask_s)) == 0)
-    cand_l = jnp.packbits((h & jnp.uint32(mask_l)) == 0)
+    # named scopes name each step's ops in the device trace
+    with jax.named_scope("gear_scan"):
+        g = jnp.asarray(hashing.GEAR_TABLE)[data.astype(jnp.int32)]
+        h = g
+        m = data.shape[0]
+        w = 1
+        while w < hashing.GEAR_WINDOW:
+            shifted = jnp.concatenate([jnp.zeros(w, jnp.uint32),
+                                       h[:m - w]])
+            h = h + shifted * jnp.uint32((1 << w) & 0xFFFFFFFF)
+            w *= 2
+    with jax.named_scope("candidate_pack"):
+        cand_s = jnp.packbits((h & jnp.uint32(mask_s)) == 0)
+        cand_l = jnp.packbits((h & jnp.uint32(mask_l)) == 0)
     return h, cand_s, cand_l
 
 
@@ -231,14 +235,17 @@ def _extract_fused(stream_hashes: jax.Array, offsets: jax.Array,
     """[Spad] u32 hashes + [Bpad] offsets/lengths -> [Bpad, M] features."""
     _TRACES.append((stream_hashes.shape, offsets.shape, lmax, k, n,
                     normalize, use_kernel))
-    sub = stream_subchunk_maxgear(stream_hashes, offsets, lengths,
-                                  k=k, lmax=lmax)
-    ids = _feat.shingle_ids(sub, n)
-    ids, mask = _feat.unique_mask(ids)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.shingle_embed(ids, mask, a, b, normalize=normalize)
-    return _feat.embed_shingles_j(ids, mask, a, b, normalize)
+    with jax.named_scope("segment_max"):
+        sub = stream_subchunk_maxgear(stream_hashes, offsets, lengths,
+                                      k=k, lmax=lmax)
+    with jax.named_scope("unique"):
+        ids = _feat.shingle_ids(sub, n)
+        ids, mask = _feat.unique_mask(ids)
+    with jax.named_scope("embed"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            return kops.shingle_embed(ids, mask, a, b, normalize=normalize)
+        return _feat.embed_shingles_j(ids, mask, a, b, normalize)
 
 
 def extract_stream(stream_hashes: np.ndarray, offsets: np.ndarray,

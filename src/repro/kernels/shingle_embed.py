@@ -63,5 +63,6 @@ def shingle_embed_sum(ids: jax.Array, mask: jax.Array, a: jax.Array,
         out_specs=pl.BlockSpec((block_b, m), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, m), jnp.float32),
         interpret=interpret,
+        name="shingle_embed",
     )(ids, mask.astype(jnp.float32), a, b)
     return out[:bsz]

@@ -73,5 +73,6 @@ def sim_topk(q: jax.Array, index: jax.Array, block_b: int = 8,
             jax.ShapeDtypeStruct((bp, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="sim_topk",
     )(q, index)
     return best[:bsz, 0], arg[:bsz, 0]

@@ -12,6 +12,10 @@ max size (64 KiB). The Pallas ``gear_hash`` kernel is left out: its
 (1, 8192) block breaks the (8, 128) tiling rule and it is not on the
 store's path.
 
+The programs' named scopes (gear scan, candidate packing, segment max,
+unique, embed) and kernel names are asserted in the lowered text, so
+that a device trace can split each program's time by step.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
 """
@@ -64,11 +68,20 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _scopes(lowered) -> str:
+    """The lowered program with its op locations, where named scopes
+    and kernel names show."""
+    return lowered.as_text(debug_info=True)
+
+
 def test_scan_compiles(one_chip):
     cfg = chunking.ChunkerConfig()
-    compiled = kingest._scan_fused.lower(
+    lowered = kingest._scan_fused.lower(
         _spec(one_chip, (SPAD,), jnp.uint8),
-        mask_s=cfg.mask_s, mask_l=cfg.mask_l).compile()
+        mask_s=cfg.mask_s, mask_l=cfg.mask_l)
+    text = _scopes(lowered)
+    assert "gear_scan" in text and "candidate_pack" in text
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == SPAD
     # everything the program holds must fit one 16 GB chip
@@ -81,31 +94,40 @@ def test_extract_with_kernel_compiles(one_chip, monkeypatch):
     # jax.default_backend() is "cpu" here, so steer the kernel wrapper to
     # the compiled (Mosaic) mode the chip host selects
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    compiled = kingest._extract_fused.lower(
+    lowered = kingest._extract_fused.lower(
         _spec(one_chip, (SPAD,), jnp.uint32),
         _spec(one_chip, (BPAD,), jnp.int32),
         _spec(one_chip, (BPAD,), jnp.int32),
         _spec(one_chip, (FEAT.m,), jnp.uint32),
         _spec(one_chip, (FEAT.m,), jnp.uint32),
         k=FEAT.k, n=FEAT.n, lmax=LMAX, normalize=True,
-        use_kernel=True).compile()
+        use_kernel=True)
+    text = _scopes(lowered)
+    for name in ("segment_max", "unique", "embed",
+                 'kernel_name = "shingle_embed"'):
+        assert name in text, name
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sim_topk_compiles(one_chip):
-    compiled = _topk.sim_topk.lower(
+    lowered = _topk.sim_topk.lower(
         _spec(one_chip, (8192, D), jnp.float32),
         _spec(one_chip, (INDEX_ROWS, D), jnp.float32),
-        interpret=False).compile()
+        interpret=False)
+    assert 'kernel_name = "sim_topk"' in _scopes(lowered)
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_shingle_embed_compiles(one_chip):
     s = FEAT.num_shingles
-    compiled = _shingle.shingle_embed_sum.lower(
+    lowered = _shingle.shingle_embed_sum.lower(
         _spec(one_chip, (BPAD, s), jnp.uint32),
         _spec(one_chip, (BPAD, s), jnp.bool_),
         _spec(one_chip, (1, FEAT.m), jnp.uint32),
         _spec(one_chip, (1, FEAT.m), jnp.uint32),
-        interpret=False).compile()
+        interpret=False)
+    assert 'kernel_name = "shingle_embed"' in _scopes(lowered)
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()

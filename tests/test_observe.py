@@ -373,12 +373,13 @@ def test_ingest_metrics_and_spans(tmp_path):
     assert parsed["types"]["repro_store_dcr"] == "gauge"
     stages = {l["stage"] for n, l, v in parsed["samples"]
               if n == "repro_ingest_stage_seconds_count" and v >= 1}
-    assert stages == {"chunk", "extract", "score", "observe", "delta",
-                      "store"}
+    assert stages == {"chunk", "dedup", "extract", "score", "search",
+                      "observe", "base_read", "delta", "store"}
+    assert parsed["types"]["repro_jax_compiles_total"] == "counter"
     ops = store.observe.tracer.ops()
     assert ops["ingest"] == 1
-    for stage in ("chunk", "extract", "score", "observe", "delta",
-                  "store"):
+    for stage in ("chunk", "dedup", "extract", "score", "observe",
+                  "delta", "store"):
         assert ops[f"ingest.{stage}"] == 1, stage
     store.close()
 
@@ -401,11 +402,12 @@ def test_restore_metrics_cache_hits_and_spans(tmp_path):
                (("outcome", "hit"),))] > 0
     stages = {l["stage"] for n, l, v in parsed["samples"]
               if n == "repro_restore_stage_seconds_count" and v >= 1}
-    assert stages == {"total", "read", "decode"}
+    assert stages == {"total", "read", "decode", "join"}
     ops = store.observe.tracer.ops()
-    for op in ("restore", "restore.plan", "restore.read",
-               "restore.decode", "restore.prefetch"):
+    for op in ("restore", "restore.plan", "restore.fetch",
+               "restore.join"):
         assert ops[op] == 2, op
+    assert "restore.prefetch" not in ops
     restores = [e for e in store.observe.tracer.events()
                 if e["op"] == "restore"]
     assert restores[-1]["hit_ratio"] > 0        # warm pass hit the cache
@@ -583,3 +585,199 @@ def test_bench_helpers_zero_division_guards():
     assert common.ratio(6, 3) == 2.0
     assert common.fmt_ratio(5, 0) == "n/a"
     assert common.fmt_ratio(1, 3, places=3) == "0.333"
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock (DESIGN.md §12.3)
+
+INGEST_PASSES = ("ingest.chunk", "ingest.dedup", "ingest.extract",
+                 "ingest.score", "ingest.delta", "ingest.store",
+                 "ingest.observe")
+
+
+def _card_store(tmp_path, **extra):
+    cfg = api.DedupConfig.from_dict({
+        "detector": "card",
+        "detector_args": {"use_kernel": False, "model": {"steps": 5}},
+        "chunker_args": {"avg_size": 2048},
+        "backend": "file",
+        "backend_args": {"path": str(tmp_path / "card")},
+        **extra})
+    return api.build_store(cfg)
+
+
+def _versions(n=2, size=256 << 10):
+    import random
+    rnd = random.Random(7)
+    v = bytearray(rnd.randbytes(size))
+    out = [bytes(v)]
+    for _ in range(n - 1):
+        at = rnd.randrange(size - 64)
+        v[at:at + 64] = rnd.randbytes(64)
+        out.append(bytes(v))
+    return out
+
+
+def _inside(child, parent, slack=1e-6):
+    return (child["t0"] >= parent["t0"] - slack
+            and child["t0"] + child["s"] <= parent["t0"] + parent["s"]
+            + slack)
+
+
+def test_commit_spans_nest_in_order(tmp_path):
+    store = _card_store(tmp_path, trace_ring_events=1024)
+    vs = _versions()
+    store.fit(vs[:1])
+    for v in vs:
+        with store.open_stream() as s:
+            s.write(v)
+    r = s.report
+    events = store.observe.tracer.events()
+    root = [e for e in events if e["op"] == "ingest"][-1]
+    mine = [e for e in events if e["id"] > root["id"]
+            and e["op"].startswith("ingest.")]
+    by = {e["op"]: e for e in mine}
+    assert sorted(by) == sorted(INGEST_PASSES + ("ingest.search",))
+    assert all(sum(e["op"] == op for e in mine) == 1 for op in by)
+    passes = [by[op] for op in INGEST_PASSES]
+    for e in passes:
+        assert e["parent"] == root["id"] and _inside(e, root), e["op"]
+    for a, b in zip(passes, passes[1:]):       # in order, no overlap
+        assert a["t0"] + a["s"] <= b["t0"] + 1e-6, (a["op"], b["op"])
+    search = by["ingest.search"]
+    assert search["parent"] == by["ingest.score"]["id"]
+    assert _inside(search, by["ingest.score"])
+    # the report's stage fields are the spans' durations
+    assert r.chunk_seconds == pytest.approx(by["ingest.chunk"]["s"])
+    assert r.dedup_seconds == pytest.approx(by["ingest.dedup"]["s"])
+    assert r.search_seconds == pytest.approx(search["s"])
+    assert r.commit_seconds == pytest.approx(root["s"])
+    assert 0 < r.delta_seconds + r.base_read_seconds \
+        <= by["ingest.delta"]["s"] + 1e-6
+    assert r.base_reads >= r.base_read_hits >= 0 and r.delta_chunks
+    assert [op for op, _, _ in r.spans] == ["ingest", "ingest.chunk",
+        "ingest.dedup", "ingest.extract", "ingest.score", "ingest.search",
+        "ingest.delta", "ingest.store", "ingest.observe"]
+    assert abs(r.spans[0][1] / 1e9 - root["t0"]) < 1e-6
+    assert root["handle"] == r.handle and root["bytes_in"] == r.bytes_in
+    store.close()
+
+
+def test_restore_spans_have_real_starts(tmp_path):
+    store = _traced_store(tmp_path)
+    data = os.urandom(96 << 10)
+    with store.open_stream() as s:
+        s.write(data)
+    h = s.report.handle
+    tr = store.observe.tracer
+    for call, want in ((lambda: store.restore(h), data),
+                       (lambda: store.restore_range(h, 500, 9000),
+                        data[500:9500]),
+                       (lambda: b"".join(store.restore_iter(h, 4)), data)):
+        n0 = len(tr.events())
+        assert call() == want
+        new = tr.events()[n0:]
+        root = new[-1]
+        assert root["op"] == "restore" and root["parent"] is None
+        kids = new[:-1]
+        assert {e["op"] for e in kids} <= {"restore.plan", "restore.fetch",
+                                           "restore.join"}
+        assert {"restore.plan", "restore.fetch"} <= {e["op"] for e in kids}
+        for e in kids:
+            assert e["parent"] == root["id"] and _inside(e, root), e["op"]
+        starts = [e["t0"] for e in kids if e["op"] != "restore.fetch"]
+        assert starts == sorted(starts) and len(set(starts)) == len(starts)
+        fetch = [e for e in kids if e["op"] == "restore.fetch"]
+        assert sum(e["cache_hits"] + e["cache_misses"] for e in fetch) \
+            == root["cache_hits"] + root["cache_misses"]
+        if root["surface"] != "iter":
+            join = [e for e in kids if e["op"] == "restore.join"]
+            assert len(join) == 1
+            assert store.last_restore.join_seconds == pytest.approx(
+                join[0]["s"])
+        assert store.last_restore.seconds == pytest.approx(root["s"])
+    assert "restore.prefetch" not in tr.ops()
+    assert store.stats.restore_join_seconds > 0
+    store.close()
+
+
+def test_spans_share_the_profiler_clock(tmp_path):
+    """Each ring event starts where the profiler's ``repro.<op>`` event
+    does: the trace's host times are relative to its session start."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    store = _traced_store(tmp_path)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with store.open_stream() as s:
+            s.write(os.urandom(64 << 10))
+        assert store.restore(s.report.handle)
+    finally:
+        jax.profiler.stop_trace()
+    ring = store.observe.tracer.events()
+    store.close()
+    path, = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    start, spans = None, {}
+    for plane in ProfileData.from_file(path).planes:
+        for k, v in plane.stats:
+            if k == "profile_start_time":
+                start = int(v)
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(observe.PROFILER_PREFIX):
+                    spans.setdefault(ev.name, []).append(int(ev.start_ns))
+    assert start is not None
+    assert len(ring) >= 9
+    for op in {e["op"] for e in ring}:
+        ours = sorted(e["t0"] for e in ring if e["op"] == op)
+        theirs = sorted((start + t) / 1e9 for t in spans["repro." + op])
+        assert len(ours) == len(theirs), op
+        for a, b in zip(ours, theirs):
+            assert abs(a - b) < 5e-4, (op, a - b)
+
+
+def test_store_without_tracer_books_nothing(tmp_path):
+    traced = _traced_store(tmp_path)
+    before = len(traced.observe.tracer.events())
+    cfg = api.DedupConfig.from_dict({"detector": "dedup-only",
+                                     "chunker_args": {"avg_size": 4096}})
+    plain = api.build_store(cfg)
+    assert plain.observe.tracer is None
+    data = os.urandom(64 << 10)
+    with traced.observe.tracer.span("outer"):
+        with plain.open_stream() as s:      # a root with no tracer
+            s.write(data)
+        assert plain.restore_range(s.report.handle, 10, 100) == \
+            data[10:110]
+    r = s.report
+    assert r.commit_seconds > 0 and r.chunk_seconds > 0
+    assert r.dedup_seconds > 0 and r.store_seconds > 0
+    assert r.commit_seconds >= r.chunk_seconds + r.dedup_seconds \
+        + r.store_seconds
+    assert [op for op, _, _ in r.spans][:3] == ["ingest", "ingest.chunk",
+                                                "ingest.dedup"]
+    assert plain.last_restore.seconds > 0
+    assert plain.last_restore.join_seconds >= 0
+    # the untraced store's spans book into no ring, not even the
+    # traced store's span they ran under
+    assert [e["op"] for e in traced.observe.tracer.events()[before:]] \
+        == ["outer"]
+    plain.close()
+    traced.close()
+
+
+def test_span_stack_survives_errors():
+    tr = Tracer(ring_events=16)
+    with pytest.raises(KeyError):
+        with observe.Span("top", tr):
+            with observe.Span("top.inner"):
+                raise KeyError("x")
+    with observe.Span("after", tr):
+        pass
+    events = {e["op"]: e for e in tr.events()}
+    assert events["top.inner"]["error"] == "KeyError"
+    assert events["top.inner"]["parent"] == events["top"]["id"]
+    assert events["after"]["parent"] is None
