@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hashing
-from repro.kernels import gear_hash, ops, ref, shingle_embed, sim_topk
+from repro.kernels import gear_hash, ref, shingle_embed, sim_topk
 
 
 def _t(fn, *args, reps=5):
@@ -25,14 +25,14 @@ def run() -> list[dict]:
     rng = np.random.Generator(np.random.PCG64(0))
     rows = []
 
-    g = jnp.asarray(rng.integers(0, 2**32, size=(64, 8192), dtype=np.uint32))
-    weights = tuple(int(w) for w in hashing.GEAR_WEIGHTS)
-    ref_us = _t(lambda x: ref.windowed_sum_ref(x, np.asarray(weights, np.uint32)), g)
-    kern = gear_hash.windowed_sum(g, weights, interpret=True)
-    oracle = ref.windowed_sum_ref(g, np.asarray(weights, np.uint32))
-    rows.append({"bench": "kernels", "name": "gear_hash.windowed_sum",
-                 "shape": "64x8192", "us_per_call_ref": round(ref_us, 1),
-                 "allclose": bool(np.array_equal(np.asarray(kern), np.asarray(oracle)))})
+    data = rng.integers(0, 256, size=1 << 19, dtype=np.uint8)
+    ref_us = _t(lambda x: hashing.gear_hashes_np(x), data)
+    kern, _, _ = gear_hash.gear_scan(jnp.asarray(data), mask_s=0xFF,
+                                     mask_l=0xF, interpret=True)
+    rows.append({"bench": "kernels", "name": "gear_hash.gear_scan",
+                 "shape": "524288", "us_per_call_ref": round(ref_us, 1),
+                 "allclose": bool(np.array_equal(
+                     np.asarray(kern), hashing.gear_hashes_np(data)))})
 
     ids = jnp.asarray(rng.integers(0, 2**32, size=(256, 61), dtype=np.uint32))
     mask = jnp.ones((256, 61), jnp.float32)

@@ -1,7 +1,7 @@
 """The paper's comparison targets: N-transform and Finesse resemblance
-detection (super-feature schemes), implemented over the same parallel
-window-fingerprint scan as CARD (kernels/gear_hash generalizes to any
-tap-weight vector — DESIGN.md §3).
+detection (super-feature schemes), over windowed Rabin-style
+fingerprints (``hashing.rabin_fps_np``, the same linear-window
+formulation as CARD's gear scan — DESIGN.md §3).
 
 Both schemes map a chunk to `sf_count` super-features; two chunks are
 treated as similar if ANY super-feature matches, and the first match wins

@@ -160,7 +160,7 @@ def segment_poly_hashes_np(data: np.ndarray, bounds: np.ndarray) -> np.ndarray:
 
 
 # ----------------------------------------------------------------------------
-# jnp implementations (oracles for the Pallas kernels; also usable directly)
+# jnp twins of the numpy windowed hashes (tests/test_hashing.py)
 # ----------------------------------------------------------------------------
 
 def windowed_weighted_sum_j(g: jax.Array, weights: np.ndarray) -> jax.Array:

@@ -6,12 +6,11 @@ pure-jnp oracles in ref.py. tests/test_kernels.py sweeps shapes/dtypes and
 asserts equality/allclose against the oracles; tests/test_chip_compile.py
 compiles the store's kernels for a described v5e chip.
 
-  gear_hash      windowed weighted-sum scan (gear + Rabin fingerprints):
-                 the serial rolling hashes are linear, so every position is
-                 a W-tap correlation evaluated in parallel (DESIGN.md §3).
-                 Interpret mode only: its (1, 8192) block breaks the v5e
-                 (8, 128) tiling rule, and the store scans with the jnp
-                 program in ingest.py instead
+  gear_hash      the stream scan: gear table lookup by lane permute,
+                 32-byte window by doubling (the rolling hash is linear,
+                 so every position is a 32-tap correlation evaluated in
+                 parallel, DESIGN.md §3) and the FastCDC candidate bits,
+                 packed on the MXU, in one pass (kernels/ingest._scan_fused)
   shingle_embed  multiply-shift M-hash feature accumulation (Algorithm 1)
   sim_topk       tiled cosine top-1 with running (max, argmax) — the
                  flash-attention trick applied to resemblance search

@@ -8,11 +8,14 @@ a fresh XLA compilation whenever the stream's chunk count or longest
 chunk changed. This module replaces all of it with TWO jitted device
 programs per stream:
 
-    _scan_fused      bytes [Spad] u8
-                       -> windowed gear hashes [Spad] u32 (window
-                          doubling; stays device-resident: StreamScan)
+    _scan_fused      bytes [Spad] u8, laid out as [Spad/128, 128] rows
+                       -> windowed gear hashes [Spad] u32 (table words
+                          by lane permute, window doubling with a row
+                          carry; stays device-resident: StreamScan)
                        -> bit-packed FastCDC candidate maps (to host,
-                          n/8 bytes each, for boundary selection)
+                          n/8 bytes each in np.packbits order, packed
+                          on the MXU, for boundary selection)
+                       one Pallas kernel, kernels/gear_hash.gear_scan
     _extract_fused   StreamScan + chunk offsets/lengths [Bpad]
                        -> sub-chunk maxgear LSH [B, K] (two-tier
                           scatter-free segment max)
@@ -45,8 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import features as _feat
-from repro.core import hashing
 from repro.core.features import bucket_pow2  # noqa: F401  (canonical rule)
+from repro.kernels import gear_hash as _gear
 
 # Monotonic count of XLA traces of the fused program. A trace happens
 # exactly when a (shape-bucket, static-arg) combination misses the jit
@@ -125,25 +128,12 @@ def _scan_fused(data: jax.Array, *, mask_s: int, mask_l: int):
     """[Spad] u8 -> windowed gear hashes [Spad] u32 (left on device) +
     bit-packed FastCDC boundary-candidate maps (shipped to host).
 
-    Window-doubling evaluation (see hashing.gear_hashes_np): 5 shifted
-    adds instead of 31, all uint32 wraparound, bit-identical to the
-    serial gear recurrence past the 32B warm-up."""
+    One pass of the ``gear_scan`` kernel, bit-identical to
+    ``hashing.gear_hashes_np`` (and so to the serial gear recurrence)."""
     _TRACES.append(("scan", data.shape, mask_s, mask_l))
-    # named scopes name each step's ops in the device trace
-    with jax.named_scope("gear_scan"):
-        g = jnp.asarray(hashing.GEAR_TABLE)[data.astype(jnp.int32)]
-        h = g
-        m = data.shape[0]
-        w = 1
-        while w < hashing.GEAR_WINDOW:
-            shifted = jnp.concatenate([jnp.zeros(w, jnp.uint32),
-                                       h[:m - w]])
-            h = h + shifted * jnp.uint32((1 << w) & 0xFFFFFFFF)
-            w *= 2
-    with jax.named_scope("candidate_pack"):
-        cand_s = jnp.packbits((h & jnp.uint32(mask_s)) == 0)
-        cand_l = jnp.packbits((h & jnp.uint32(mask_l)) == 0)
-    return h, cand_s, cand_l
+    from repro.kernels import ops as kops
+    return _gear.gear_scan(data, mask_s=mask_s, mask_l=mask_l,
+                           interpret=kops._interpret())
 
 
 def scan_stream(data: np.ndarray, mask_s: int, mask_l: int

@@ -12,10 +12,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core import hashing
-from repro.kernels import gear_hash as _gear
 from repro.kernels import shingle_embed as _shingle
 from repro.kernels import sim_topk as _topk
 
@@ -29,39 +26,6 @@ def _interpret() -> bool:
         raise RuntimeError(f"Pallas kernels run on tpu (compiled) or cpu "
                            f"(interpret mode), not on {platform!r}")
     return platform == "cpu"
-
-
-ROW_WIDTH = 8192
-
-
-def _to_rows(stream: jax.Array, width: int = ROW_WIDTH) -> tuple[jax.Array, int]:
-    """Lay a stream out as [R, C] rows, padding R up to a power of two so
-    the row-grid kernels (grid=(R,)) compile once per bucket instead of
-    once per stream length (DESIGN.md §8)."""
-    n = stream.shape[0]
-    rows = max(1, -(-n // width))
-    rows = 1 << (rows - 1).bit_length()
-    pad = rows * width - n
-    if pad:
-        stream = jnp.pad(stream, (0, pad))
-    return stream.reshape(-1, width), n
-
-
-def gear_hashes(data: jax.Array) -> jax.Array:
-    """[n] uint8 byte stream -> [n] uint32 windowed gear hashes."""
-    g = jnp.asarray(hashing.GEAR_TABLE)[data.astype(jnp.int32)]
-    rows, n = _to_rows(g)
-    weights = tuple(int(w) for w in hashing.GEAR_WEIGHTS)
-    out = _gear.windowed_sum(rows, weights, interpret=_interpret())
-    return out.reshape(-1)[:n]
-
-
-def rabin_fps(data: jax.Array, window: int = hashing.RABIN_WINDOW) -> jax.Array:
-    """[n] uint8 byte stream -> [n] uint32 windowed polynomial fingerprints."""
-    rows, n = _to_rows(data.astype(jnp.uint32))
-    weights = tuple(int(w) for w in hashing.poly_powers(window))
-    out = _gear.windowed_sum(rows, weights, interpret=_interpret())
-    return out.reshape(-1)[:n]
 
 
 def shingle_embed(ids: jax.Array, mask: jax.Array, a: jax.Array, b: jax.Array,

@@ -1,6 +1,7 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for the Pallas kernels in this package.
 
 Each kernel's tests sweep shapes/dtypes and assert_allclose against these.
+The ``gear_scan`` kernel's oracle is the numpy ``hashing.gear_hashes_np``.
 """
 from __future__ import annotations
 
@@ -8,15 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import hashing
 from repro.core.features import embed_shingles_j
-
-
-def windowed_sum_ref(g: jax.Array, weights: np.ndarray) -> jax.Array:
-    """h_i = sum_k weights[k] * g_{i-k} over the *flattened* [R, C] stream."""
-    r, c = g.shape
-    flat = hashing.windowed_weighted_sum_j(g.reshape(-1), weights)
-    return flat.reshape(r, c)
 
 
 def shingle_embed_ref(ids: jax.Array, mask: jax.Array, a: jax.Array,

@@ -8,13 +8,11 @@ in HBM). Interpret-mode tests cannot see those faults.
 Widths are the ones ``chip_smoke.py`` drives at its defaults: 128 MiB
 streams land in the 256 MiB scan bucket, ~8k chunks of 16 KiB average
 pad to B = 16384 rows, and the longest-chunk extent is the chunker's
-max size (64 KiB). The Pallas ``gear_hash`` kernel is left out: its
-(1, 8192) block breaks the (8, 128) tiling rule and it is not on the
-store's path.
+max size (64 KiB). The scan is one Pallas kernel, ``gear_scan``.
 
-The programs' named scopes (gear scan, candidate packing, segment max,
-unique, embed) and kernel names are asserted in the lowered text, so
-that a device trace can split each program's time by step.
+The programs' named scopes (segment max, unique, embed) and kernel
+names are asserted in the lowered text, so that a device trace can
+split each program's time by step.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
@@ -74,16 +72,27 @@ def _scopes(lowered) -> str:
     return lowered.as_text(debug_info=True)
 
 
-def test_scan_compiles(one_chip):
+# temp_size_in_bytes of the 256 MiB-bucket scan before it was one kernel
+# (a gather of GEAR_TABLE, five shifted copies and jnp.packbits)
+SCAN_TEMP_BEFORE = 4831934976
+
+
+def test_scan_compiles(one_chip, monkeypatch):
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
     cfg = chunking.ChunkerConfig()
     lowered = kingest._scan_fused.lower(
         _spec(one_chip, (SPAD,), jnp.uint8),
         mask_s=cfg.mask_s, mask_l=cfg.mask_l)
-    text = _scopes(lowered)
-    assert "gear_scan" in text and "candidate_pack" in text
+    assert 'kernel_name = "gear_scan"' in _scopes(lowered)
     compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the table lookup is a lane permute inside the kernel, never an
+    # XLA gather of one index per stream byte
+    assert "gather" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == SPAD
+    assert mem.temp_size_in_bytes <= SCAN_TEMP_BEFORE
     # everything the program holds must fit one 16 GB chip
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import chunking, features, hashing
+from repro.kernels import gear_hash as kernel_gear
 from repro.kernels import ingest
 
 
@@ -250,6 +251,45 @@ def test_streamscan_indexes_like_numpy():
     assert np.array_equal(np.asarray(scan), ref)
     assert np.array_equal(cand_s, (ref & np.uint32(0xFF)) == 0)
     assert np.array_equal(cand_l, (ref & np.uint32(0xF)) == 0)
+
+
+_LANES = kernel_gear.LANES
+_BLOCK = kernel_gear.BLOCK_ROWS * _LANES
+
+
+def _all_bytes(n, rng):
+    return rng.permutation(np.tile(np.arange(256, dtype=np.uint8), n // 256))
+
+
+@pytest.mark.parametrize("n,masks,make", [
+    (1, None, None),                                # below the window
+    (31, None, None),
+    (_LANES - 1, None, None),                       # one row of the layout
+    (_LANES, None, None),
+    (_LANES + 1, None, None),
+    (_LANES + 31, None, None),                      # the row halo
+    (ingest._FLOOR_STREAM, None, None),             # exactly the floor bucket
+    (_BLOCK + 31, None, None),                      # the block halo
+    (1 << 20, "chunker", None),                     # the store's mask pair
+    (1 << 16, None, _all_bytes),                    # every byte value
+], ids=["len1", "len31", "row-1", "row", "row+1", "row+31", "floor",
+        "block+31", "chunker-masks", "all-bytes"])
+def test_scan_matches_numpy(n, masks, make):
+    """Hashes and both candidate maps, bit for bit, against the numpy
+    window-doubling scan, through the store's entry point."""
+    rng = np.random.Generator(np.random.PCG64(n))
+    data = (make(n, rng) if make else
+            rng.integers(0, 256, size=n, dtype=np.uint8))
+    if masks == "chunker":
+        cfg = chunking.ChunkerConfig()
+        mask_s, mask_l = cfg.mask_s, cfg.mask_l
+    else:
+        mask_s, mask_l = 0xFF, 0xF
+    scan, cand_s, cand_l = ingest.scan_stream(data, mask_s, mask_l)
+    ref = hashing.gear_hashes_np(data)
+    assert np.array_equal(scan.asnumpy(), ref)
+    assert np.array_equal(cand_s, (ref & np.uint32(mask_s)) == 0)
+    assert np.array_equal(cand_l, (ref & np.uint32(mask_l)) == 0)
 
 
 def _scan_and_extract(n=5000):

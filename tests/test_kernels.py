@@ -9,32 +9,45 @@ from repro.core import hashing
 from repro.kernels import gear_hash, ops, ref, shingle_embed, sim_topk
 
 
-class TestWindowedSum:
-    @pytest.mark.parametrize("r,c", [(1, 256), (3, 512), (7, 8192), (2, 128)])
-    @pytest.mark.parametrize("taps", [4, 32, 48])
-    def test_vs_ref(self, r, c, taps):
-        if c < taps:
-            pytest.skip("row narrower than window")
-        rng = np.random.Generator(np.random.PCG64(r * 1000 + c + taps))
-        g = rng.integers(0, 2**32, size=(r, c), dtype=np.uint32)
-        weights = tuple(int(w) for w in hashing.poly_powers(taps))
-        got = gear_hash.windowed_sum(jnp.asarray(g), weights, interpret=True)
-        want = ref.windowed_sum_ref(jnp.asarray(g), np.asarray(weights, np.uint32))
-        assert np.array_equal(np.asarray(got), np.asarray(want))
+def _stream(kind: str, n: int, seed: int) -> np.ndarray:
+    rng = np.random.Generator(np.random.PCG64(seed))
+    if kind == "random":
+        return rng.integers(0, 256, size=n, dtype=np.uint8)
+    if kind == "zeros":
+        return np.zeros(n, np.uint8)
+    if kind == "ramp":
+        return (np.arange(n) % 256).astype(np.uint8)
+    # long runs of one byte, the shape of zero-filled VM image blocks
+    return np.repeat(rng.integers(0, 256, size=n // 300 + 1,
+                                  dtype=np.uint8), 300)[:n]
 
-    @pytest.mark.parametrize("n", [100, 8192, 8193, 40000])
-    def test_gear_ops_vs_serial(self, n):
-        rng = np.random.Generator(np.random.PCG64(n))
-        data = rng.integers(0, 256, size=n, dtype=np.uint8)
-        got = np.asarray(ops.gear_hashes(jnp.asarray(data)))
-        assert np.array_equal(got, hashing.gear_hashes_np(data))
 
-    @pytest.mark.parametrize("window", [16, 48])
-    def test_rabin_ops_vs_np(self, window):
-        rng = np.random.Generator(np.random.PCG64(window))
-        data = rng.integers(0, 256, size=20000, dtype=np.uint8)
-        got = np.asarray(ops.rabin_fps(jnp.asarray(data), window))
-        assert np.array_equal(got, hashing.rabin_fps_np(data, window))
+class TestGearScan:
+    """The scan kernel against numpy, on one block and across blocks,
+    where the 31-byte halo comes from the block before."""
+
+    @pytest.mark.parametrize("blocks", [0.25, 1, 2, 4])
+    @pytest.mark.parametrize("kind", ["random", "zeros", "ramp", "runs"])
+    def test_vs_numpy(self, blocks, kind):
+        n = int(blocks * gear_hash.BLOCK_ROWS) * gear_hash.LANES
+        data = _stream(kind, n, n + len(kind))
+        h, cs, cl = gear_hash.gear_scan(jnp.asarray(data), mask_s=0xFF,
+                                        mask_l=0x7, interpret=True)
+        want = hashing.gear_hashes_np(data)
+        assert np.array_equal(np.asarray(h), want)
+        assert np.array_equal(np.unpackbits(np.asarray(cs)),
+                              (want & np.uint32(0xFF)) == 0)
+        assert np.array_equal(np.unpackbits(np.asarray(cl)),
+                              (want & np.uint32(0x7)) == 0)
+
+    def test_serial_recurrence(self):
+        # shifts of 32 and more vanish, so the serial FastCDC hash with
+        # zeros before the stream's head is the windowed one everywhere
+        data = _stream("random", 1 << 16, 5)
+        h, _, _ = gear_hash.gear_scan(jnp.asarray(data), mask_s=1, mask_l=1,
+                                      interpret=True)
+        assert np.array_equal(np.asarray(h),
+                              hashing.gear_hashes_serial_np(data))
 
 
 class TestShingleEmbed:
