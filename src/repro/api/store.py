@@ -232,12 +232,18 @@ class DedupStore:
                          "Chunk dispositions at commit (DESIGN.md §2.2)",
                          labels={"kind": k})
             for k in ("dup", "delta", "raw")}
+        self._c_ingest_bases = {
+            k: m.counter("repro_ingest_bases_total",
+                         "Chosen delta bases by where the detector found "
+                         "them: its index or the stream's earlier chunks",
+                         labels={"source": k})
+            for k in ("index", "stream")}
         self._h_ingest_stage = {
             s: m.histogram("repro_ingest_stage_seconds",
                            "Per-commit ingest phase timings (§8)",
                            labels={"stage": s}, bounds=om.SECONDS_BUCKETS)
             for s in ("chunk", "dedup", "extract", "score", "search",
-                      "observe", "base_read", "delta", "store")}
+                      "intra", "observe", "base_read", "delta", "store")}
         self._c_jax_compiles = m.counter(
             "repro_jax_compiles_total",
             "Programs JAX traced on the committing thread during commits")
@@ -419,10 +425,12 @@ class DedupStore:
         # detectors mutate inside detect() and can't make that promise.
         # A zero-chunk stream (``ingest(b"")``) never reaches a detector
         # at all — neither path is required to accept an empty batch.
-        # The detector's index query is the ``ingest.search`` child of
-        # the score span.
+        # The detector's index query and intra-stream score are the
+        # ``ingest.search`` and ``ingest.intra`` children of the score
+        # span.
         extract_seconds = score_seconds = observe_seconds = 0.0
-        search_seconds = 0.0
+        search_seconds = intra_seconds = 0.0
+        index_bases = stream_bases = 0
         batch = DetectBatch(chunks=chunks, ids=ids, is_new=is_new,
                             stream_hashes=stream_hashes)
         staged = n > 0 and is_staged(self.detector)
@@ -442,6 +450,12 @@ class DedupStore:
                         chunks, ids, is_new, stream_hashes), np.int64)
             score_seconds = sp.seconds
             search_seconds = sp.child_seconds("ingest.search")
+            intra_seconds = sp.child_seconds("ingest.intra")
+            # a chosen base that is one of this stream's new chunks came
+            # from the stream; any other is already stored, so the index's
+            given = base_ids >= 0
+            stream_bases = int((given & np.isin(base_ids, ids[is_new])).sum())
+            index_bases = int(given.sum()) - stream_bases
 
         # pass 3a: delta-vs-raw decisions over a worklist — every
         # delta.encode runs here, back to back, with no backend I/O
@@ -535,6 +549,7 @@ class DedupStore:
             root.labels.update(
                 handle=handle, bytes_in=bytes_in, bytes_stored=bytes_stored,
                 chunks=n, dup_chunks=dup_chunks, delta_chunks=delta_chunks,
+                index_bases=index_bases, stream_bases=stream_bases,
                 base_reads=base_reads, base_read_hits=base_read_hits,
                 dcr=round(bytes_in / max(1, bytes_stored), 4))
         root.end()
@@ -548,6 +563,8 @@ class DedupStore:
             extract_seconds=extract_seconds, score_seconds=score_seconds,
             observe_seconds=observe_seconds, store_seconds=store_seconds,
             dedup_seconds=dedup_seconds, search_seconds=search_seconds,
+            intra_seconds=intra_seconds, index_bases=index_bases,
+            stream_bases=stream_bases,
             base_read_seconds=base_read_seconds, base_reads=base_reads,
             base_read_hits=base_read_hits, commit_seconds=root.seconds,
             compiles=traced - compiles[0],
@@ -569,13 +586,15 @@ class DedupStore:
         self._c_ingest_chunks["dup"].inc(r.dup_chunks)
         self._c_ingest_chunks["delta"].inc(r.delta_chunks)
         self._c_ingest_chunks["raw"].inc(r.raw_chunks)
+        self._c_ingest_bases["index"].inc(r.index_bases)
+        self._c_ingest_bases["stream"].inc(r.stream_bases)
         self._c_jax_compiles.inc(r.compiles)
         self._c_jax_compile_seconds.inc(r.compile_seconds)
         h = self._h_ingest_stage
         for stage, seconds in (
                 ("chunk", r.chunk_seconds), ("dedup", r.dedup_seconds),
                 ("extract", r.extract_seconds), ("score", r.score_seconds),
-                ("search", r.search_seconds),
+                ("search", r.search_seconds), ("intra", r.intra_seconds),
                 ("observe", r.observe_seconds),
                 ("base_read", r.base_read_seconds),
                 ("delta", r.delta_seconds), ("store", r.store_seconds)):

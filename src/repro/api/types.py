@@ -110,12 +110,17 @@ class IngestReport:
     observe_seconds: float = 0.0
     store_seconds: float = 0.0
     # measured by the commit's spans (DESIGN.md §12.3): pass 1's digest
-    # lookups and id assignment; the index query inside score_seconds;
+    # lookups and id assignment; the index query and the intra-stream
+    # score inside score_seconds, with the count of chosen bases that
+    # came from the index and from this stream's earlier chunks;
     # pass 3a's base lookups outside delta_seconds, with their count and
     # how many of them were this commit's own chunks (no store read);
     # the whole commit, lock waits included
     dedup_seconds: float = 0.0
     search_seconds: float = 0.0
+    intra_seconds: float = 0.0
+    index_bases: int = 0
+    stream_bases: int = 0
     base_read_seconds: float = 0.0
     base_reads: int = 0
     base_read_hits: int = 0

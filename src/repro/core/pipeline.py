@@ -206,11 +206,12 @@ class CARDDetector(LegacyDetectMixin):
             ext_ids, ext_scores = self.index.query(feats)
 
         # phase 2: intra-stream (earlier chunks of this stream)
-        sims = feats @ feats.T
-        iu = np.triu_indices(n)
-        sims[iu] = -np.inf                                   # j < i only
-        intra_j = sims.argmax(axis=1)
-        intra_s = sims[np.arange(n), intra_j]
+        with observe.Span("ingest.intra"):
+            sims = feats @ feats.T
+            iu = np.triu_indices(n)
+            sims[iu] = -np.inf                               # j < i only
+            intra_j = sims.argmax(axis=1)
+            intra_s = sims[np.arange(n), intra_j]
 
         use_intra = intra_s >= np.maximum(ext_scores, self.threshold)
         best_id = np.where(use_intra, batch.ids[intra_j], ext_ids)

@@ -374,8 +374,9 @@ def test_ingest_metrics_and_spans(tmp_path):
     stages = {l["stage"] for n, l, v in parsed["samples"]
               if n == "repro_ingest_stage_seconds_count" and v >= 1}
     assert stages == {"chunk", "dedup", "extract", "score", "search",
-                      "observe", "base_read", "delta", "store"}
+                      "intra", "observe", "base_read", "delta", "store"}
     assert parsed["types"]["repro_jax_compiles_total"] == "counter"
+    assert parsed["types"]["repro_ingest_bases_total"] == "counter"
     ops = store.observe.tracer.ops()
     assert ops["ingest"] == 1
     for stage in ("chunk", "dedup", "extract", "score", "observe",
@@ -637,30 +638,119 @@ def test_commit_spans_nest_in_order(tmp_path):
     mine = [e for e in events if e["id"] > root["id"]
             and e["op"].startswith("ingest.")]
     by = {e["op"]: e for e in mine}
-    assert sorted(by) == sorted(INGEST_PASSES + ("ingest.search",))
+    assert sorted(by) == sorted(INGEST_PASSES + ("ingest.search",
+                                                 "ingest.intra"))
     assert all(sum(e["op"] == op for e in mine) == 1 for op in by)
     passes = [by[op] for op in INGEST_PASSES]
     for e in passes:
         assert e["parent"] == root["id"] and _inside(e, root), e["op"]
     for a, b in zip(passes, passes[1:]):       # in order, no overlap
         assert a["t0"] + a["s"] <= b["t0"] + 1e-6, (a["op"], b["op"])
-    search = by["ingest.search"]
-    assert search["parent"] == by["ingest.score"]["id"]
-    assert _inside(search, by["ingest.score"])
+    search, intra = by["ingest.search"], by["ingest.intra"]
+    for e in (search, intra):           # the score's two phases, in order
+        assert e["parent"] == by["ingest.score"]["id"]
+        assert _inside(e, by["ingest.score"])
+    assert search["t0"] + search["s"] <= intra["t0"] + 1e-6
     # the report's stage fields are the spans' durations
     assert r.chunk_seconds == pytest.approx(by["ingest.chunk"]["s"])
     assert r.dedup_seconds == pytest.approx(by["ingest.dedup"]["s"])
     assert r.search_seconds == pytest.approx(search["s"])
+    assert r.intra_seconds == pytest.approx(intra["s"])
     assert r.commit_seconds == pytest.approx(root["s"])
     assert 0 < r.delta_seconds + r.base_read_seconds \
         <= by["ingest.delta"]["s"] + 1e-6
     assert r.base_reads >= r.base_read_hits >= 0 and r.delta_chunks
     assert [op for op, _, _ in r.spans] == ["ingest", "ingest.chunk",
         "ingest.dedup", "ingest.extract", "ingest.score", "ingest.search",
-        "ingest.delta", "ingest.store", "ingest.observe"]
+        "ingest.intra", "ingest.delta", "ingest.store", "ingest.observe"]
     assert abs(r.spans[0][1] / 1e9 - root["t0"]) < 1e-6
     assert root["handle"] == r.handle and root["bytes_in"] == r.bytes_in
     store.close()
+
+
+def _release(files, tag: int) -> bytes:
+    """``files`` in one stream, each behind a 64-byte header that names
+    the release, as an archive's member headers do."""
+    return b"".join(b"release %d file %d\n" % (tag, i) + bytes(40) + f
+                    for i, f in enumerate(files))
+
+
+def _files(n=64, seed=11):
+    import random
+    rnd = random.Random(seed)
+    return [rnd.randbytes(rnd.randrange(2048, 6144)) for _ in range(n)]
+
+
+def _scored(store):
+    """Record each score's verdict: the store counts bases from it."""
+    verdicts = []
+    score = store.detector.score
+
+    def record(feats, batch):
+        result = score(feats, batch)
+        verdicts.append(result)
+        return result
+    store.detector.score = record
+    return verdicts
+
+
+def _commit(store, data):
+    with store.open_stream() as s:
+        s.write(data)
+    return s.report
+
+
+def test_bases_from_the_stream_are_counted(tmp_path):
+    store = _card_store(tmp_path)
+    files = _files()
+    store.fit([_release(files, 0)])
+    verdicts = _scored(store)
+    # one stream that carries its first files twice, under other headers:
+    # the second copies find their bases earlier in the same stream
+    r = _commit(store, _release(files, 1) + _release(files[:16], 2))
+    given = int((verdicts[-1].base_ids >= 0).sum())
+    assert r.stream_bases >= 1 and r.index_bases == 0
+    assert r.index_bases + r.stream_bases == given
+    assert r.intra_seconds > 0
+    snap = store.metrics().to_json()
+    assert "repro_ingest_bases_total" in snap
+    store.close()
+
+
+def test_bases_from_the_index_are_counted(tmp_path):
+    store = _card_store(tmp_path)
+    files = _files()
+    store.fit([_release(files, 0)])
+    verdicts = _scored(store)
+    first = _commit(store, _release(files, 1))
+    # the same files, every header changed: each new chunk's base is its
+    # copy in the last release, found in the index
+    r = _commit(store, _release(files, 2))
+    assert r.dup_chunks < r.chunks and r.delta_chunks > 0
+    assert r.index_bases == r.delta_chunks and r.stream_bases == 0
+    for rep, v in zip((first, r), verdicts):
+        assert rep.index_bases + rep.stream_bases == int(
+            (v.base_ids >= 0).sum())
+    text = store.metrics().to_prometheus()
+    assert 'repro_ingest_bases_total{source="index"} %d' % (
+        first.index_bases + r.index_bases) in text
+    store.close()
+
+
+def test_bases_are_counted_for_an_unstaged_detector(tmp_path):
+    """Finesse has no staged score: the store still counts a base among
+    the stream's own new chunks as the stream's, any other as the
+    index's."""
+    store = api.build_store(api.DedupConfig.from_dict({
+        "detector": "finesse", "chunker_args": {"avg_size": 2048}}))
+    files = _files()
+    verdicts = _scored(store)
+    reports = [_commit(store, _release(files, 1) + _release(files[:16], 2)),
+               _commit(store, _release(files, 3))]
+    for rep, v in zip(reports, verdicts):
+        assert rep.index_bases + rep.stream_bases == int(
+            (v.base_ids >= 0).sum())
+    assert reports[0].stream_bases >= 1 and reports[1].index_bases >= 1
 
 
 def test_restore_spans_have_real_starts(tmp_path):

@@ -57,13 +57,16 @@ def test_rwlock_writer_timeout_unblocks_waiting_readers():
 
     def writer():
         try:
-            lock.acquire_write(timeout=0.15)
+            lock.acquire_write(timeout=1.0)
         except LockTimeout:
             timed_out.set()
 
     w = threading.Thread(target=writer, daemon=True)
     w.start()
-    time.sleep(0.03)                    # writer is now waiting
+    deadline = time.perf_counter() + JOIN_S
+    while not lock._writers_waiting:    # writer is now waiting
+        assert time.perf_counter() < deadline
+        time.sleep(0.001)
     got = threading.Event()
 
     def reader():
